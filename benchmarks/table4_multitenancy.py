@@ -19,7 +19,11 @@ overhead ledger's queue breakdown.
 
 from __future__ import annotations
 
+import jax
+import numpy as np
+
 from benchmarks.common import calibrate_costs, make_paper_roles
+from repro.configs import ARCHS, reduced
 from repro.core import ledger as L
 from repro.core.hsa.clock import VirtualClock
 from repro.core.hsa.queue import Queue
@@ -27,37 +31,29 @@ from repro.core.hsa.scheduler import Scheduler
 from repro.core.ledger import OverheadLedger
 from repro.core.reconfig import RegionManager
 from repro.core.roles import RoleLibrary
+from repro.models import build_model
+from repro.models.params import init_params
+from repro.serve.engine import ServeEngine
 
 # producer-cycle roles: 4 roles through 2 regions -> reconfig on every packet
 BG_ORDER = ("role3_conv5x5", "role4_conv3x3", "role1_fc", "role3_conv5x5")
 
 
 def _decode_workload(engine_steps: int):
-    """The decode tenant: ServeEngine driving real decode steps when the model
-    stack is available, else a matmul stand-in with the same cadence."""
-    try:
-        import jax
-        import numpy as np
+    """The decode tenant: a ServeEngine driving real decode steps."""
+    model = build_model(
+        reduced(ARCHS["llama3.2-1b"], layers=2, d_model=64, vocab=128)
+    )
+    params = init_params(model.param_specs(), jax.random.key(0))
 
-        from repro.configs import ARCHS, reduced
-        from repro.models import build_model
-        from repro.models.params import init_params
+    def make(queue, scheduler):
+        eng = ServeEngine(model, params, batch_slots=2, max_len=32,
+                          hsa_queue=queue, hsa_scheduler=scheduler)
+        eng.submit(list(np.arange(4) + 1), max_new_tokens=engine_steps)
+        eng.submit([7, 9], max_new_tokens=engine_steps)
+        return eng
 
-        cfg = reduced(ARCHS["llama3.2-1b"], layers=2, d_model=64, vocab=128)
-        model = build_model(cfg)
-        params = init_params(model.param_specs(), jax.random.key(0))
-        from repro.serve.engine import ServeEngine
-
-        def make(queue, scheduler):
-            eng = ServeEngine(model, params, batch_slots=2, max_len=32,
-                              hsa_queue=queue, hsa_scheduler=scheduler)
-            eng.submit(list(np.arange(4) + 1), max_new_tokens=engine_steps)
-            eng.submit([7, 9], max_new_tokens=engine_steps)
-            return eng
-
-        return make
-    except Exception:                      # pragma: no cover - reduced envs
-        return None
+    return make
 
 
 def _run_schedule(roles, costs, *, nbg: int, engine_steps: int,
@@ -86,14 +82,8 @@ def _run_schedule(roles, costs, *, nbg: int, engine_steps: int,
         role, args = run_roles[BG_ORDER[i % len(BG_ORDER)]]
         q_bg.dispatch(role.key, *args, producer="opencl")
 
-    make_engine = _decode_workload(engine_steps)
-    if make_engine is not None:
-        engine = make_engine(q_serve, sched)
-        engine.run_to_completion(max_steps=engine_steps + 8)
-    else:
-        role, args = run_roles["role1_fc"]
-        for _ in range(engine_steps):
-            q_serve.dispatch(role.key, *args, producer="tf-serving")
+    engine = _decode_workload(engine_steps)(q_serve, sched)
+    engine.run_to_completion(max_steps=engine_steps + 8)
     sched.run_until_idle()
     return sched, ledger
 

@@ -2,7 +2,7 @@
 
 1. A tiny MLP written once against the transparent dispatch API.
 2. The same model runs under three policies — pure-jnp reference, XLA,
-   Pallas (interpret) — with identical numerics and zero model-code changes.
+   Pallas (interpreted on a CPU) — with identical numerics and zero model-code changes.
 3. The HSA runtime path: presynthesized roles, bounded regions with LRU,
    and the Table II overhead ledger.
 
@@ -36,9 +36,12 @@ def main():
 
     print("== 1. transparent backend switch (same code, same numbers) ==")
     outs = {}
+    # Pallas kernels run compiled on a TPU; elsewhere only the interpreter
+    # can run them
+    interpret = jax.default_backend() == "cpu"
     for policy in ("reference", "xla", "pallas"):
         with dispatch.use(prefer=dispatch.policy_from_flag(policy),
-                          interpret=True):
+                          interpret=interpret):
             outs[policy] = np.asarray(tiny_mlp(x, w1, w2))
         print(f"  policy={policy:10s} out[0,:3]={np.round(outs[policy][0,:3], 4)}")
     assert np.allclose(outs["reference"], outs["xla"], atol=1e-4)

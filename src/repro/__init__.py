@@ -5,6 +5,4 @@ A production-grade reproduction and TPU-native extension of
 Reichenbach; 2021).
 """
 
-from repro import _compat  # noqa: F401  (jax forward-compat aliases)
-
 __version__ = "1.0.0"

@@ -13,7 +13,7 @@ from typing import Any
 
 import jax
 
-from repro.hw import DEFAULT_CHIP
+from repro.hw import chip_spec
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,7 +33,7 @@ class Agent:
         self.name = f"{self.kind}:{device.id}"
         self.num_reconfig_regions = num_reconfig_regions
         if self.kind == "tpu":
-            chip = DEFAULT_CHIP
+            chip = chip_spec(device.device_kind)   # unknown kind: KeyError
             self.regions = (
                 MemoryRegion("HBM", chip.hbm_bytes, "global", chip.hbm_bw),
                 MemoryRegion("VMEM", chip.vmem_bytes, "group"),

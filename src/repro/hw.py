@@ -1,7 +1,8 @@
-"""Target-hardware constants (TPU v5e) — single source of truth.
+"""Accelerator peaks, keyed by the ``device_kind`` JAX reports.
 
 Used by the roofline analysis, the agent descriptors, and kernel BlockSpec
-sizing.  This container executes on CPU; these constants describe the TARGET.
+sizing.  A TPU whose kind is not in :data:`CHIPS` has no peaks here:
+:func:`chip_spec` raises rather than lend it another chip's numbers.
 """
 
 from __future__ import annotations
@@ -27,10 +28,14 @@ class ChipSpec:
         return self.peak_bf16_flops / self.clock_hz
 
 
+# Google Cloud documentation, "TPU v5e" (system architecture table): 197
+# TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect (4 links).  VMEM is not in that table; the 128 MiB
+# below has no published source and is unverified.
 TPU_V5E = ChipSpec(
     name="tpu_v5e",
     peak_bf16_flops=197e12,
-    peak_int8_ops=394e12,
+    peak_int8_ops=393e12,
     hbm_bytes=16 * 1024**3,
     hbm_bw=819e9,
     vmem_bytes=128 * 1024**2,
@@ -38,7 +43,18 @@ TPU_V5E = ChipSpec(
     ici_links=4,
 )
 
-# The evaluation host of the paper (Ultra96: ARM Cortex-A53) — kept only for
-# benchmark narration; OP/cycle comparisons in benchmarks/table3 are measured
-# on this container's host CPU instead.
-DEFAULT_CHIP = TPU_V5E
+#: ``jax.Device.device_kind`` -> peaks
+CHIPS: dict[str, ChipSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """Peaks of the chip JAX names ``device_kind``; KeyError if unknown."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peaks recorded for device kind {device_kind!r}; "
+            f"known: {sorted(CHIPS)}"
+        ) from None

@@ -29,9 +29,10 @@ from repro.core.registry import ResourceFootprint
 NEG_INF = -1e30
 
 
-def _dec_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
+def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                 m_ref, l_ref, acc_ref,
                 *, scale: float, block_k: int, n_k: int) -> None:
+    b = pl.program_id(0)
     ti = pl.program_id(2)
 
     @pl.when(ti == 0)
@@ -43,7 +44,7 @@ def _dec_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)              # [group, hd]
     k = k_ref[0, 0].astype(jnp.float32)              # [bk, hd]
     v = v_ref[0, 0].astype(jnp.float32)              # [bk, hd]
-    length = len_ref[0]
+    length = len_ref[b]
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [group, bk]
     kpos = ti * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -93,24 +94,29 @@ def decode_attention(
     qg = q.reshape(B, Hkv, group, hd)
 
     kernel = functools.partial(_dec_kernel, scale=scale, block_k=bk, n_k=n_k)
-    out = pl.pallas_call(
-        kernel,
+    # lengths ride in SMEM as a scalar-prefetch operand, as in the paged
+    # kernel: Mosaic refuses a (1,)-block of a rank-1 VMEM array
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                    # lengths
         grid=(B, Hkv, n_k),                       # KV innermost
         in_specs=[
-            pl.BlockSpec((1, 1, group, hd), lambda b, h, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, t: (b, h, t, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, t: (b, h, t, 0)),
-            pl.BlockSpec((1,), lambda b, h, t: (b,)),
+            pl.BlockSpec((1, 1, group, hd), lambda b, h, t, ln: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, t, ln: (b, h, t, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, t, ln: (b, h, t, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, hd), lambda b, h, t: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, group, hd), lambda b, h, t, ln: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, hd), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, hd), q.dtype),
         interpret=interpret,
-    )(qg, k_cache, v_cache, lengths)
+    )(lengths, qg, k_cache, v_cache)
     return out.reshape(B, Hq, hd)
 
 

@@ -12,6 +12,7 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, get_arch, reduced
 from repro.models import build_model
 from repro.models.params import init_params
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -19,10 +19,11 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, get_arch, reduced
 from repro.data.pipeline import DataConfig, SyntheticTokens
 from repro.dist.sharding import ShardingRules
-from repro.launch.mesh import make_mesh, make_production_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import pick_opt
 from repro.models import build_model
 from repro.train.loop import LoopConfig, TrainLoop
@@ -40,6 +41,45 @@ def parse_mesh(spec: str):
     return make_mesh(dims, ("data", "model"))
 
 
+def train(cfg, mesh, *, steps: int, global_batch: int, seq: int,
+          lr: float = 3e-4, microbatches: int = 0, seed: int = 0,
+          ckpt_dir: str | None = None, ckpt_every: int = 100):
+    """Build ``cfg``'s sharded train step on ``mesh`` and run ``steps`` steps
+    of synthetic tokens from parameters drawn from ``seed``.
+
+    Returns ``(params, opt_state, report)``; ``report.losses`` holds the loss
+    of every step run.
+    """
+    rules = ShardingRules.for_arch(cfg, mesh)
+    model = build_model(cfg)
+    opt = dataclasses.replace(pick_opt(cfg), lr=lr,
+                              decay_steps=max(steps, 10))
+    mb = microbatches or auto_microbatches(global_batch, seq, rules, cfg=cfg)
+    data = SyntheticTokens(DataConfig(
+        vocab_size=cfg.vocab_size, seq_len=seq, global_batch=global_batch,
+    ))
+    shape = "x".join(str(n) for n in mesh.devices.shape)
+
+    with jax.set_mesh(mesh):
+        step, *_ = make_train_step(model, opt, rules,
+                                   global_batch=global_batch,
+                                   microbatches=mb)
+        params, opt_state = init_train_state(model, opt, rules,
+                                             jax.random.key(seed))
+        n = sum(x.size for x in jax.tree.leaves(params))
+        print(f"[train] {cfg.name}: {n/1e6:.1f}M params, mesh={shape}, "
+              f"microbatches={mb}, opt={opt.kind}", flush=True)
+
+        def batch_at(s: int):
+            return {k: jnp.asarray(v) for k, v in data.batch_at(s).items()}
+
+        loop = TrainLoop(step, batch_at, LoopConfig(
+            total_steps=steps, ckpt_dir=ckpt_dir, ckpt_every=ckpt_every,
+            log_every=10,
+        ))
+        return loop.run(params, opt_state)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=sorted(ARCHS))
@@ -55,43 +95,19 @@ def main() -> None:
                     help="0 = auto (activation-budget heuristic)")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-    mesh = parse_mesh(args.mesh)
-    rules = ShardingRules.for_arch(cfg, mesh)
-    model = build_model(cfg)
-    opt = dataclasses.replace(pick_opt(cfg), lr=args.lr,
-                              decay_steps=max(args.steps, 10))
-    mb = args.microbatches or auto_microbatches(
-        args.global_batch, args.seq, rules, cfg=cfg
+    _, _, report = train(
+        cfg, parse_mesh(args.mesh), steps=args.steps,
+        global_batch=args.global_batch, seq=args.seq, lr=args.lr,
+        microbatches=args.microbatches, ckpt_dir=args.ckpt_dir,
+        ckpt_every=args.ckpt_every,
     )
-    data = SyntheticTokens(DataConfig(
-        vocab_size=cfg.vocab_size, seq_len=args.seq,
-        global_batch=args.global_batch,
-    ))
-
-    with jax.set_mesh(mesh):
-        step, *_ = make_train_step(model, opt, rules,
-                                   global_batch=args.global_batch,
-                                   microbatches=mb)
-        params, opt_state = init_train_state(model, opt, rules,
-                                             jax.random.key(0))
-        n = sum(x.size for x in jax.tree.leaves(params))
-        print(f"[train] {cfg.name}: {n/1e6:.1f}M params, mesh={args.mesh}, "
-              f"microbatches={mb}, opt={opt.kind}")
-
-        def batch_at(s: int):
-            return {k: jnp.asarray(v) for k, v in data.batch_at(s).items()}
-
-        loop = TrainLoop(step, batch_at, LoopConfig(
-            total_steps=args.steps, ckpt_dir=args.ckpt_dir,
-            ckpt_every=args.ckpt_every, log_every=10,
-        ))
-        _, _, report = loop.run(params, opt_state)
-        print(f"[train] done: {report.steps_run} steps, "
-              f"loss={report.last_metrics.get('loss', float('nan')):.4f}")
+    print(f"[train] done: {report.steps_run} steps, "
+          f"loss={report.last_metrics.get('loss', float('nan')):.4f}")
 
 
 if __name__ == "__main__":

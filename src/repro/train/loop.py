@@ -49,6 +49,7 @@ class LoopReport:
     resumed_from: int = 0
     stragglers: list[int] = dataclasses.field(default_factory=list)
     last_metrics: dict = dataclasses.field(default_factory=dict)
+    losses: list[float] = dataclasses.field(default_factory=list)
     step_times_s: list[float] = dataclasses.field(default_factory=list)
     preempted: bool = False
 
@@ -108,6 +109,7 @@ class TrainLoop:
                 loss = float(jax.device_get(metrics["loss"]))
                 dt = time.perf_counter() - t0
                 report.step_times_s.append(dt)
+                report.losses.append(loss)
                 report.steps_run += 1
                 report.last_metrics = {
                     k: float(np.asarray(jax.device_get(v)).mean())

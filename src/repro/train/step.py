@@ -125,6 +125,15 @@ def batch_shardings(cfg: ArchConfig, rules: ShardingRules, global_batch: int) ->
     return out
 
 
+def opt_shardings(opt_cfg: OptConfig, spec_tree, rules: ShardingRules):
+    """NamedSharding tree of the optimizer state for ``spec_tree``'s params."""
+    return jax.tree.map(
+        lambda ps: NamedSharding(rules.mesh, ps),
+        opt_state_specs(opt_cfg, spec_tree, rules.pspec),
+        is_leaf=lambda x: isinstance(x, P),
+    )
+
+
 def make_train_step(
     model: DecoderLM | EncDecLM,
     opt_cfg: OptConfig,
@@ -136,14 +145,9 @@ def make_train_step(
 ):
     """Returns (jitted step fn, param shardings, opt shardings, batch shardings)."""
     cfg = model.cfg
-    mesh = rules.mesh
     spec_tree = model.param_specs()
     p_shard = rules.sharding_tree(spec_tree)
-    o_pspec = opt_state_specs(opt_cfg, spec_tree, rules.pspec)
-    o_shard = jax.tree.map(
-        lambda ps: NamedSharding(mesh, ps), o_pspec,
-        is_leaf=lambda x: isinstance(x, P),
-    )
+    o_shard = opt_shardings(opt_cfg, spec_tree, rules)
     b_shard = batch_shardings(cfg, rules, global_batch)
     minfo = moe_mesh_info(cfg, rules)
 
@@ -208,5 +212,9 @@ def init_train_state(model, opt_cfg: OptConfig, rules: ShardingRules, rng):
     params = init_params(spec_tree, rng)
     p_shard = rules.sharding_tree(spec_tree)
     params = jax.tree.map(jax.device_put, params, p_shard)
-    opt_state = opt_init(opt_cfg, params)
+    # the step's in_shardings demand the state's own layout: zeros made
+    # under the mesh would come out replicated
+    opt_state = jax.device_put(
+        opt_init(opt_cfg, params), opt_shardings(opt_cfg, spec_tree, rules)
+    )
     return params, opt_state

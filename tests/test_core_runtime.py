@@ -394,3 +394,46 @@ def test_executor_surfaces_kernel_errors():
             run_packet_sync(ex, q, pkt)
     finally:
         hsa_shut_down()
+
+
+def test_agent_takes_tpu_peaks_from_its_device_kind():
+    import types
+
+    from repro import hw
+
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite", id=0)
+    agent = Agent(v5e)
+    hbm = next(r for r in agent.regions if r.name == "HBM")
+    assert (hbm.size_bytes, hbm.bandwidth_bps) == (
+        hw.TPU_V5E.hbm_bytes, hw.TPU_V5E.hbm_bw
+    )
+    other = types.SimpleNamespace(platform="tpu", device_kind="TPU v9", id=0)
+    with pytest.raises(KeyError, match="TPU v9"):
+        Agent(other)
+    cpu = types.SimpleNamespace(platform="cpu", device_kind="cpu", id=0)
+    assert [r.name for r in Agent(cpu).regions] == ["RAM"]
+
+
+def test_compile_cache_defers_to_the_environment(monkeypatch):
+    from repro import compile_cache
+
+    monkeypatch.setenv(compile_cache.ENV_VAR, "/elsewhere")
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    from pathlib import Path
+
+    from repro import compile_cache
+
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = compile_cache.enable_compile_cache()
+        root = Path(__file__).resolve().parents[1]
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -15,9 +15,11 @@ import pytest
 import repro.models.layers as layers_mod
 from repro.configs import ARCHS, reduced
 from repro.models import build_model
+from repro.models import reference
 from repro.models.params import abstract_params, count_params, init_params
 
 ALL_ARCHS = sorted(ARCHS)
+REFERENCE_ARCHS = [n for n in ALL_ARCHS if reference.supports(ARCHS[n])]
 RNG = np.random.default_rng(42)
 
 
@@ -162,3 +164,26 @@ def test_full_config_abstract_params_match_published_size(name):
     # and nothing was materialized
     ap = abstract_params(specs)
     assert all(isinstance(x, jax.ShapeDtypeStruct) for x in jax.tree.leaves(ap))
+
+
+@pytest.mark.parametrize("name", REFERENCE_ARCHS)
+def test_float32_reference_matches_forward(name):
+    """The plain jnp reference computes the model's function (exact in f32)."""
+    cfg = _smoke_cfg(name)
+    m = build_model(cfg)
+    params = _f32(init_params(m.param_specs(), jax.random.key(11)))
+    tokens = _batch(cfg, 1, 24)["tokens"]
+    old = layers_mod.COMPUTE_DTYPE
+    layers_mod.COMPUTE_DTYPE = jnp.float32
+    try:
+        want, _ = m.forward(params, {"tokens": tokens})
+    finally:
+        layers_mod.COMPUTE_DTYPE = old
+    got = reference.logits(cfg, params, tokens[0])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want[0]),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_float32_reference_refuses_other_families():
+    with pytest.raises(ValueError, match="no float32 reference"):
+        reference.logits(_smoke_cfg("mamba2-780m"), {}, jnp.zeros(4, jnp.int32))
