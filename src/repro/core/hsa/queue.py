@@ -18,7 +18,6 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import threading
-import time
 from typing import Any, Callable, Sequence
 
 from repro.core import ledger as ledger_mod
@@ -187,9 +186,9 @@ class Queue:
             )
 
     def submit(self, packet: Packet) -> int:
-        t0 = time.perf_counter_ns()
-        idx = self._write_packets((packet,))
-        self._record_submit((packet,), (time.perf_counter_ns() - t0) * 1e-9)
+        with ledger_mod.span("hsa.submit", queue=self.name) as leg:
+            idx = self._write_packets((packet,))
+        self._record_submit((packet,), leg.seconds)
         return idx
 
     def submit_burst(self, packets: Sequence[Packet]) -> int:
@@ -206,26 +205,27 @@ class Queue:
         packets = list(packets)
         if not packets:
             raise ValueError("submit_burst needs at least one packet")
-        t0 = time.perf_counter_ns()
-        bid = next(_BURST_IDS)
-        unstamped = [p for p in packets if p.enqueue_t is None]
-        for packet in packets:
-            packet.burst_id = bid
-            packet.burst_n = len(packets)
-        try:
-            idx = self._write_packets(packets)
-        except QueueFullError:
-            # nothing was written: revert the burst stamps so a caller that
-            # falls back to individual submits doesn't carry a dead burst_id
-            # (which would fuse its retries into one grant pass) or a stale
-            # enqueue_t (which would inflate WAIT on retry)
+        with ledger_mod.span("hsa.submit", queue=self.name,
+                             burst=len(packets)) as leg:
+            bid = next(_BURST_IDS)
+            unstamped = [p for p in packets if p.enqueue_t is None]
             for packet in packets:
-                packet.burst_id = None
-                packet.burst_n = 1
-            for packet in unstamped:
-                packet.enqueue_t = None
-            raise
-        self._record_submit(packets, (time.perf_counter_ns() - t0) * 1e-9)
+                packet.burst_id = bid
+                packet.burst_n = len(packets)
+            try:
+                idx = self._write_packets(packets)
+            except QueueFullError:
+                # nothing was written: revert the burst stamps so a caller
+                # that falls back to individual submits doesn't carry a dead
+                # burst_id (which would fuse its retries into one grant pass)
+                # or a stale enqueue_t (which would inflate WAIT on retry)
+                for packet in packets:
+                    packet.burst_id = None
+                    packet.burst_n = 1
+                for packet in unstamped:
+                    packet.enqueue_t = None
+                raise
+        self._record_submit(packets, leg.seconds)
         return idx
 
     def dispatch(

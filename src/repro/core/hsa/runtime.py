@@ -38,7 +38,7 @@ class HsaSystem:
         scheduler_policy: str = "round_robin",
     ) -> None:
         self.ledger = ledger
-        with ledger.timed(ledger_mod.SETUP, what="hsa_init"):
+        with ledger.span("hsa.init", ledger_mod.SETUP, what="hsa_init"):
             self.agents = Agent.discover(num_reconfig_regions=num_regions)
             self.library = RoleLibrary(ledger=ledger)
             self.queues: dict[str, Queue] = {}             # default queue per agent

@@ -46,11 +46,8 @@ from __future__ import annotations
 import dataclasses
 import random
 import threading
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Iterable
-
-import jax
 
 from repro.core import ledger as ledger_mod
 from repro.core.ledger import GLOBAL_LEDGER, OverheadLedger
@@ -960,71 +957,54 @@ class Scheduler:
 
     def _exec(self, q: Queue, pkt: KernelDispatchPacket, role: Any,
               now: float) -> SchedEvent:
-        g0 = time.perf_counter_ns()        # grant leg: pick-up -> launch returned
-        start = max(now, self._compute_free_t, self._deps_time(pkt.deps, now))
-        q.pop()
-        st = self.stats[q.name]
-        if getattr(pkt, "_attempts", 1) == 1:
-            # retries keep the original enqueue_t; WAIT is the first attempt's
-            # (the retry delay is priced separately as RETRY backoff)
-            wait = max(
-                0.0,
-                start - (pkt.enqueue_t if pkt.enqueue_t is not None else start),
-            )
-            st.wait_s += wait
-            self.ledger.record(
-                ledger_mod.WAIT, wait, queue=q.name, what=pkt.what,
-                producer=pkt.producer,
-            )
-        self._log(start, "exec_start", q.name, pkt.what)
+        meta = dict(role=pkt.what, producer=pkt.producer, queue=q.name)
+        # grant leg: pick-up -> launch returned (recorded only on success)
+        with self.ledger.span("hsa.grant", **meta) as grant:
+            start = max(now, self._compute_free_t,
+                        self._deps_time(pkt.deps, now))
+            q.pop()
+            st = self.stats[q.name]
+            if getattr(pkt, "_attempts", 1) == 1:
+                # retries keep the original enqueue_t; WAIT is the first
+                # attempt's (the retry delay is priced separately as RETRY)
+                enqueued = pkt.enqueue_t if pkt.enqueue_t is not None else start
+                wait = max(0.0, start - enqueued)
+                st.wait_s += wait
+                self.ledger.record(
+                    ledger_mod.WAIT, wait, queue=q.name, what=pkt.what,
+                    producer=pkt.producer,
+                )
+            self._log(start, "exec_start", q.name, pkt.what)
 
-        fault = (
-            self.faults.draw_exec(pkt.what, queue=q.name)
-            if self.faults is not None else None
-        )
-        wedged = isinstance(fault, WedgedLaunch)
-        measured = 0.0
-        if fault is not None:
-            pkt.out.error = fault
-        else:
-            try:
-                t0 = time.perf_counter_ns()
-                if role is not None:
-                    if getattr(pkt, "_reconfigured", False):
-                        # stall already accounted this packet's lookup; if the role
-                        # was evicted meanwhile (or its reconfig failed), re-load
-                        # properly instead of executing outside region management
-                        if not self.regions.touch(role.key):
-                            # lazy protect: the window scan only runs if this
-                            # lookup actually misses and must evict
-                            self.regions.ensure_resident(
-                                role, queue=q.name, protect=self._protected_keys
-                            )
-                    else:
-                        self.regions.ensure_resident(
-                            role, queue=q.name, protect=self._protected_keys
-                        )
-                    out = role(*pkt.args)
+            fault = (
+                self.faults.draw_exec(pkt.what, queue=q.name)
+                if self.faults is not None else None
+            )
+            wedged = isinstance(fault, WedgedLaunch)
+            launch = None
+            if fault is not None:
+                pkt.out.error = fault
+            else:
+                try:
+                    with self.ledger.span("hsa.dispatch", ledger_mod.DISPATCH,
+                                          **meta) as launch:
+                        out = self._launch(q, pkt, role)
+                except BaseException as e:  # surface to waiter, keep the loop
+                    pkt.out.error = e
+                    launch = None
                 else:
-                    out = pkt.fn(*pkt.args)
-                t1 = time.perf_counter_ns()
-                self.ledger.record(
-                    ledger_mod.DISPATCH, (t1 - t0) * 1e-9,
-                    role=pkt.what, producer=pkt.producer, queue=q.name,
-                )
-                self.ledger.record(
-                    ledger_mod.DISPATCH_GRANT, (t1 - g0) * 1e-9,
-                    role=pkt.what, producer=pkt.producer, queue=q.name,
-                    burst=pkt.burst_n,
-                )
-                out = jax.block_until_ready(out)
-                t2 = time.perf_counter_ns()
-                self.ledger.record(
-                    ledger_mod.EXEC, (t2 - t1) * 1e-9, role=pkt.what, queue=q.name
-                )
-                measured = (t2 - t0) * 1e-9
+                    self.ledger.record(ledger_mod.DISPATCH_GRANT,
+                                       grant.elapsed(), **meta,
+                                       burst=pkt.burst_n)
+        measured = 0.0
+        if launch is not None:
+            try:
+                with self.ledger.span("hsa.exec", ledger_mod.EXEC,
+                                      role=pkt.what, queue=q.name) as ex:
+                    out = ledger_mod.host_sync(self.ledger, out, copy=False)
+                measured = launch.seconds + ex.seconds
                 pkt.out.value = out
-            except BaseException as e:      # surface to waiter, don't kill the loop
+            except BaseException as e:
                 pkt.out.error = e
 
         if wedged:
@@ -1054,6 +1034,27 @@ class Scheduler:
         ev = self._log(end, "exec_end", q.name, pkt.what)
         self._complete(pkt.completion, end, error=err)
         return ev
+
+    def _launch(self, q: Queue, pkt: KernelDispatchPacket, role: Any) -> Any:
+        """Call the packet's kernel: a role through region management, or a
+        pinned-shell callable directly."""
+        if role is None:
+            return pkt.fn(*pkt.args)
+        if getattr(pkt, "_reconfigured", False):
+            # stall already accounted this packet's lookup; if the role
+            # was evicted meanwhile (or its reconfig failed), re-load
+            # properly instead of executing outside region management
+            if not self.regions.touch(role.key):
+                # lazy protect: the window scan only runs if this
+                # lookup actually misses and must evict
+                self.regions.ensure_resident(
+                    role, queue=q.name, protect=self._protected_keys
+                )
+        else:
+            self.regions.ensure_resident(
+                role, queue=q.name, protect=self._protected_keys
+            )
+        return role(*pkt.args)
 
     # -- cooperative driving -------------------------------------------------------
 

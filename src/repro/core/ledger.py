@@ -16,6 +16,12 @@ categories:
 
 All entries are *measured* wall times (perf_counter_ns), never simulated
 constants.  ``table()`` renders the Table II layout; benchmarks/table2 uses it.
+
+:func:`span` is the one way host work is timed for a trace: it opens a
+``jax.profiler`` annotation, so a running profiler shows the span on the
+device trace's clock, and records its duration in a category when given one.
+Nothing is configured; with no profiler running a span costs a few
+microseconds.
 """
 
 from __future__ import annotations
@@ -25,9 +31,11 @@ import math
 import threading
 import time
 from collections import deque
-from typing import Any, Iterator
+from typing import Any
 
-import contextlib
+import jax
+import numpy as np
+from jax.profiler import TraceAnnotation
 
 SETUP = "setup"
 RECONFIG = "reconfig"
@@ -220,6 +228,11 @@ class OverheadLedger:
         "stale_region": "stale_regions",
     }
 
+    _SYNC_ZERO = {
+        "engine_steps": 0.0,        # ServeEngine.step calls
+        "host_syncs": 0.0,          # waits for the device (see host_sync)
+    }
+
     _PREFIX_ZERO = {
         "prefix_lookups": 0.0, "prefix_hits": 0.0,
         "shared_pages": 0.0,        # gauge: pages with refcount > 1 now
@@ -242,6 +255,7 @@ class OverheadLedger:
         self._spill: dict[str, float] = dict(self._SPILL_ZERO)
         self._integrity: dict[str, float] = dict(self._INTEGRITY_ZERO)
         self._prefix: dict[str, float] = dict(self._PREFIX_ZERO)
+        self._sync: dict[str, float] = dict(self._SYNC_ZERO)
 
     def record(self, category: str, seconds: float, **meta: Any) -> None:
         if category not in self._stats:
@@ -287,13 +301,10 @@ class OverheadLedger:
         with self._lock:
             return sorted(self._by_producer)
 
-    @contextlib.contextmanager
-    def timed(self, category: str, **meta: Any) -> Iterator[None]:
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            self.record(category, (time.perf_counter_ns() - t0) * 1e-9, **meta)
+    def span(self, name: str, category: str | None = None,
+             **meta: Any) -> "Span":
+        """:func:`span` recording into this ledger."""
+        return Span(name, self, category, meta)
 
     def stat(self, category: str) -> Stat:
         with self._lock:
@@ -335,6 +346,7 @@ class OverheadLedger:
             self._spill = dict(self._SPILL_ZERO)
             self._integrity = dict(self._INTEGRITY_ZERO)
             self._prefix = dict(self._PREFIX_ZERO)
+            self._sync = dict(self._SYNC_ZERO)
             if self._entries is not None:
                 self._entries = []
 
@@ -713,6 +725,27 @@ class OverheadLedger:
         )
         return out
 
+    # -- host/device hand-offs (what a step waits on) -------------------------
+
+    def record_engine_step(self) -> None:
+        """One ``ServeEngine.step``."""
+        with self._lock:
+            self._sync["engine_steps"] += 1.0
+
+    def record_host_syncs(self, n: int = 1) -> None:
+        """``n`` host waits for the device (see :func:`host_sync`)."""
+        with self._lock:
+            self._sync["host_syncs"] += float(n)
+
+    def sync_split(self) -> dict[str, float]:
+        """Engine steps, host syncs, and syncs per step (0.0 with no
+        steps)."""
+        with self._lock:
+            out = dict(self._sync)
+        steps = out["engine_steps"]
+        out["host_syncs_per_step"] = out["host_syncs"] / steps if steps else 0.0
+        return out
+
     def reconfig_split(self) -> dict[str, float]:
         """Exposed vs hidden reconfiguration time (scheduler-clock seconds).
 
@@ -811,6 +844,62 @@ class OverheadLedger:
         lines = ["  ".join(c.ljust(w) for c, w in zip(r, widths)) for r in rows]
         lines.insert(1, "-" * len(lines[0]))
         return "\n".join(lines)
+
+
+class Span:
+    """A named stretch of host work; see :func:`span`."""
+
+    __slots__ = ("_annotation", "_ledger", "_category", "_meta", "_t0",
+                 "seconds")
+
+    def __init__(self, name: str, ledger: OverheadLedger | None,
+                 category: str | None, meta: dict[str, Any]) -> None:
+        self._annotation = TraceAnnotation(name, **meta)
+        self._ledger, self._category, self._meta = ledger, category, meta
+        self.seconds = 0.0
+
+    def __enter__(self) -> "Span":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.seconds = (time.perf_counter_ns() - self._t0) * 1e-9
+        self._annotation.__exit__(exc_type, exc, tb)
+        if (exc_type is None and self._category is not None
+                and self._ledger is not None):
+            self._ledger.record(self._category, self.seconds, **self._meta)
+
+    def elapsed(self) -> float:
+        """Seconds since the span opened."""
+        return (time.perf_counter_ns() - self._t0) * 1e-9
+
+
+def span(name: str, ledger: OverheadLedger | None = None,
+         category: str | None = None, **meta: Any) -> Span:
+    """A context manager over host work named ``name``: a
+    ``jax.profiler.TraceAnnotation(name, **meta)`` (the profiler keeps
+    ``meta`` as the span's metadata), its duration in ``.seconds`` once the
+    block ends, and, with ``ledger`` and ``category``, that duration
+    recorded there with ``meta`` when the block exits without an
+    exception."""
+    return Span(name, ledger, category, meta)
+
+
+def host_sync(ledger: OverheadLedger | None, x: Any, *,
+              copy: bool = True) -> Any:
+    """Wait on the host for ``x``, an array or a tree of arrays: its values
+    as numpy arrays, one host sync per leaf, or with ``copy=False`` ``x``
+    itself once computed (``jax.block_until_ready``), one sync.  The syncs
+    are counted in ``ledger`` (``None`` counts nothing)."""
+    if copy:
+        out = jax.tree.map(np.asarray, x)
+        n = len(jax.tree.leaves(out))
+    else:
+        out, n = jax.block_until_ready(x), 1
+    if ledger is not None:
+        ledger.record_host_syncs(n)
+    return out
 
 
 GLOBAL_LEDGER = OverheadLedger(keep_entries=False)

@@ -187,7 +187,8 @@ class RoleLibrary:
         Recorded under the ledger's SETUP category — the paper's one-time cost.
         """
         total = 0.0
-        with self.ledger.timed(ledger_mod.SETUP, what="synthesize_all", n=len(self._roles)):
+        with self.ledger.span("hsa.synthesize", ledger_mod.SETUP,
+                              what="synthesize_all", n=len(self._roles)):
             for role in self._roles.values():
                 if role.source == PRESYNTHESIZED:
                     total += role.synthesize()

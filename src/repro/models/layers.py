@@ -89,6 +89,7 @@ def embed_tokens(p: Params, tokens: jax.Array) -> jax.Array:
     return jnp.take(p["tok"], tokens, axis=0).astype(COMPUTE_DTYPE)
 
 
+@jax.named_scope("unembed")
 def unembed(p: Params, h: jax.Array) -> jax.Array:
     if "unembed" in p:
         out = dispatch.op("matmul", h, p["unembed"], out_dtype=jnp.float32)
@@ -101,6 +102,12 @@ def unembed(p: Params, h: jax.Array) -> jax.Array:
 
 # ---------------------------------------------------------------------------
 # GQA attention
+#
+# The serving paths name their parts with ``jax.named_scope`` (metadata
+# only: the HLO computes the same thing), so a device trace can split a
+# step program by part: ``attn.qkv`` (projections and RoPE),
+# ``attn.kv_write``, ``attn.core`` (the attention op), ``attn.out``, and
+# ``mlp`` and ``unembed`` below.
 # ---------------------------------------------------------------------------
 
 
@@ -136,17 +143,20 @@ def attention_full(
     causal: bool = True,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Full-sequence attention (train/prefill). Returns (y, k, v) post-rope."""
-    q, k, v = _qkv(p, x, cfg)
-    cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)
-    q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    out = dispatch.op(
-        "flash_attention",
-        q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
-        causal=causal,
-        window=window,
-    ).swapaxes(1, 2)                    # [B, S, H, hd]
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _qkv(p, x, cfg)
+        cos, sin = rope_table(positions, cfg.head_dim, cfg.rope_theta)
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    with jax.named_scope("attn.core"):
+        out = dispatch.op(
+            "flash_attention",
+            q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
+            causal=causal,
+            window=window,
+        ).swapaxes(1, 2)                # [B, S, H, hd]
     B, S = x.shape[:2]
-    y = dispatch.op("matmul", out.reshape(B, S, -1), p["wo"])
+    with jax.named_scope("attn.out"):
+        y = dispatch.op("matmul", out.reshape(B, S, -1), p["wo"])
     return y, k.swapaxes(1, 2), v.swapaxes(1, 2)   # caches as [B, Hkv, S, hd]
 
 
@@ -176,23 +186,27 @@ def attention_prefill_chunk(
     """
     B, Sc, _ = x.shape
     hd = cfg.head_dim
-    q, k, v = _qkv(p, x, cfg)
-    positions = start + jnp.arange(Sc)
-    cos, sin = rope_table(positions, hd, cfg.rope_theta)
-    q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    cache_k = jax.lax.dynamic_update_slice(
-        cache_k, k.swapaxes(1, 2).astype(cache_k.dtype), (0, 0, start, 0)
-    )
-    cache_v = jax.lax.dynamic_update_slice(
-        cache_v, v.swapaxes(1, 2).astype(cache_v.dtype), (0, 0, start, 0)
-    )
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _qkv(p, x, cfg)
+        positions = start + jnp.arange(Sc)
+        cos, sin = rope_table(positions, hd, cfg.rope_theta)
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    with jax.named_scope("attn.kv_write"):
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, k.swapaxes(1, 2).astype(cache_k.dtype), (0, 0, start, 0)
+        )
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, v.swapaxes(1, 2).astype(cache_v.dtype), (0, 0, start, 0)
+        )
     end = start + Sc
-    out = dispatch.op(
-        "flash_attention",
-        q.swapaxes(1, 2), cache_k[:, :, :end], cache_v[:, :, :end],
-        causal=True,
-    ).swapaxes(1, 2)                    # [B, Sc, H, hd]
-    y = dispatch.op("matmul", out.reshape(B, Sc, -1), p["wo"])
+    with jax.named_scope("attn.core"):
+        out = dispatch.op(
+            "flash_attention",
+            q.swapaxes(1, 2), cache_k[:, :, :end], cache_v[:, :, :end],
+            causal=True,
+        ).swapaxes(1, 2)                # [B, Sc, H, hd]
+    with jax.named_scope("attn.out"):
+        y = dispatch.op("matmul", out.reshape(B, Sc, -1), p["wo"])
     return y, cache_k, cache_v
 
 
@@ -296,14 +310,8 @@ def attention_decode(
     """Single-token decode against a (ring-buffered, if windowed) KV cache."""
     from repro.dist import act
 
-    B = x.shape[0]
-    hd = cfg.head_dim
     Tc = cache_k.shape[2]
-    q, k, v = _qkv(p, x, cfg)
-    cos, sin = rope_table(decode_positions(pos), hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)[:, 0]                     # [B, H, hd]
-    k = apply_rope(k, cos, sin)[:, 0]                     # [B, Hkv, hd]
-    v = v[:, 0]
+    q, k, v = _decode_qkv(p, x, pos, cfg)
 
     # sequence-parallel path: serving, kv heads don't divide TP, full cache
     rules = act.current()
@@ -314,16 +322,31 @@ def attention_decode(
         out, cache_k, cache_v = _sp_decode_attention(
             q, k, v, cache_k, cache_v, pos, cfg, rules
         )
-        y = dispatch.op("matmul", out.reshape(B, 1, -1)[:, 0], p["wo"])
-        return y[:, None, :], cache_k, cache_v
+        return _decode_out(p, out), cache_k, cache_v
 
     slot = pos % Tc                     # ring buffer when windowed; pos < Tc otherwise
-    cache_k = write_kv(cache_k, k, slot)
-    cache_v = write_kv(cache_v, v, slot)
+    with jax.named_scope("attn.kv_write"):
+        cache_k = write_kv(cache_k, k, slot)
+        cache_v = write_kv(cache_v, v, slot)
     length = jnp.minimum(pos + 1, Tc)
-    out = dispatch.op("decode_attention", q, cache_k, cache_v, length)
-    y = dispatch.op("matmul", out.reshape(B, 1, -1)[:, 0], p["wo"])
-    return y[:, None, :], cache_k, cache_v
+    with jax.named_scope("attn.core"):
+        out = dispatch.op("decode_attention", q, cache_k, cache_v, length)
+    return _decode_out(p, out), cache_k, cache_v
+
+
+@jax.named_scope("attn.qkv")
+def _decode_qkv(p: Params, x: jax.Array, pos: jax.Array, cfg: ArchConfig):
+    """One decode token's q [B, H, hd], k and v [B, Hkv, hd], post-RoPE."""
+    q, k, v = _qkv(p, x, cfg)
+    cos, sin = rope_table(decode_positions(pos), cfg.head_dim, cfg.rope_theta)
+    return apply_rope(q, cos, sin)[:, 0], apply_rope(k, cos, sin)[:, 0], v[:, 0]
+
+
+@jax.named_scope("attn.out")
+def _decode_out(p: Params, out: jax.Array) -> jax.Array:
+    """The output projection of one decode token's attention, [B, 1, d]."""
+    y = dispatch.op("matmul", out.reshape(out.shape[0], 1, -1)[:, 0], p["wo"])
+    return y[:, None, :]
 
 
 def paged_write_kv(pool: jax.Array, new: jax.Array, page: jax.Array,
@@ -358,25 +381,20 @@ def attention_decode_paged(
     dense, not merely allclose).
     """
     B = x.shape[0]
-    hd = cfg.head_dim
     ps = k_pages.shape[2]
-    q, k, v = _qkv(p, x, cfg)
-    cos, sin = rope_table(decode_positions(pos), hd, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)[:, 0]                     # [B, H, hd]
-    k = apply_rope(k, cos, sin)[:, 0]                     # [B, Hkv, hd]
-    v = v[:, 0]
-
+    q, k, v = _decode_qkv(p, x, pos, cfg)
     posb = jnp.broadcast_to(pos, (B,)) if pos.ndim == 0 else pos
-    page = jnp.take_along_axis(
-        block_table, (posb // ps)[:, None], axis=1
-    )[:, 0]
-    k_pages = paged_write_kv(k_pages, k, page, posb % ps)
-    v_pages = paged_write_kv(v_pages, v, page, posb % ps)
-    out = dispatch.op(
-        "paged_decode_attention", q, k_pages, v_pages, block_table, posb + 1
-    )
-    y = dispatch.op("matmul", out.reshape(B, 1, -1)[:, 0], p["wo"])
-    return y[:, None, :], k_pages, v_pages
+    with jax.named_scope("attn.kv_write"):
+        page = jnp.take_along_axis(
+            block_table, (posb // ps)[:, None], axis=1
+        )[:, 0]
+        k_pages = paged_write_kv(k_pages, k, page, posb % ps)
+        v_pages = paged_write_kv(v_pages, v, page, posb % ps)
+    with jax.named_scope("attn.core"):
+        out = dispatch.op(
+            "paged_decode_attention", q, k_pages, v_pages, block_table, posb + 1
+        )
+    return _decode_out(p, out), k_pages, v_pages
 
 
 def cross_attention_specs(cfg: ArchConfig) -> Params:
@@ -427,6 +445,7 @@ def mlp_specs(cfg: ArchConfig, d_ff: int | None = None) -> Params:
     }
 
 
+@jax.named_scope("mlp")
 def apply_mlp(p: Params, x: jax.Array) -> jax.Array:
     g = dispatch.op("matmul", x, p["wg"], activation="silu")
     u = dispatch.op("matmul", x, p["wu"])

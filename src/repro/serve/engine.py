@@ -182,8 +182,10 @@ class Request:
     # regenerated tokens match this prefix bitwise, then drops it
     replay: list[int] | None = None
     # engine-clock timestamps (None until the event happens): arrival at
-    # submit, first generated token, completion — the TTFT/TPOT feed
+    # submit, first admission into a slot (queue wait ends, prefill starts),
+    # first generated token, completion — the TTFT/TPOT feed
     arrival_t: float | None = None
+    admit_t: float | None = None
     first_token_t: float | None = None
     finish_t: float | None = None
 
@@ -559,23 +561,21 @@ class ServeEngine:
         else:
             call = fn
         pkt = self._hsa_queue.call(call, *args, producer=self._producer)
-        t0 = time.perf_counter_ns()
-        if getattr(self._hsa_scheduler, "running", False):
-            # the scheduler's worker thread owns the consume side: never run
-            # the cooperative loop concurrently, just wait for completion
-            pkt.completion.wait_eq(0)
-        else:
-            # drain only our queue: another tenant's dep-blocked packet must
-            # not wedge (or deadlock) a decode step
-            self._hsa_scheduler.drain(self._hsa_queue)
-        if self._hsa_queue.ledger is not None:
-            # the producer-blocked leg of the packet round trip (overlaps the
-            # device execution it waits on; subtract EXEC for pure overhead)
-            self._hsa_queue.ledger.record(
-                ledger_mod.DISPATCH_WAIT, (time.perf_counter_ns() - t0) * 1e-9,
-                queue=self._hsa_queue.name, producer=self._producer,
-                what=getattr(call, "__name__", "serve_step"),
-            )
+        # the producer-blocked leg of the packet round trip (overlaps the
+        # device execution it waits on; subtract EXEC for pure overhead)
+        with ledger_mod.span(
+            "hsa.wait", self._hsa_queue.ledger, ledger_mod.DISPATCH_WAIT,
+            queue=self._hsa_queue.name, producer=self._producer,
+            what=getattr(call, "__name__", "serve_step"),
+        ):
+            if getattr(self._hsa_scheduler, "running", False):
+                # the scheduler's worker thread owns the consume side: never
+                # run the cooperative loop concurrently, just wait for it
+                pkt.completion.wait_eq(0)
+            else:
+                # drain only our queue: another tenant's dep-blocked packet
+                # must not wedge (or deadlock) a decode step
+                self._hsa_scheduler.drain(self._hsa_queue)
         if pkt.out.error is not None:
             raise pkt.out.error
         return pkt.out.value
@@ -1186,7 +1186,8 @@ class ServeEngine:
             # discipline (park copies only the private tail).
             keep = paged_mod.pages_for(pos, self.page_size)
             snapshot = paged_mod.gather_pages(
-                self._cache["segments"], self._table[slot, shared:keep]
+                self._cache["segments"], self._table[slot, shared:keep],
+                ledger=self.ledger,
             )
             snap_bytes = paged_mod.snapshot_bytes(snapshot)
             # the snapshot spills D2H into the budgeted host arena; if the
@@ -1309,8 +1310,8 @@ class ServeEngine:
                     )
                     self._pos[slot] = entry.pos
                     self._projected[slot] = self._projected_pages(req)
-                    self._slot_key[slot] = np.asarray(
-                        jax.random.fold_in(self._base_key, req.uid)
+                    self._slot_key[slot] = ledger_mod.host_sync(
+                        self.ledger, jax.random.fold_in(self._base_key, req.uid)
                     )
                     self._seal_slot_pages(slot, entry.pos)
                     if x.corrupted or req.uid in self._tainted_uids:
@@ -1671,6 +1672,8 @@ class ServeEngine:
         return {"sustained": sustained, "peak": float(self.peak_concurrency)}
 
     def _prefill_slot(self, slot: int, req: Request) -> None:
+        if req.admit_t is None:
+            req.admit_t = self.clock.now()
         if self.prefix is not None and self.paged:
             shared = self._lookup_prefix(req.prompt, req.uid)
             self._count_prefix_lookup(shared)
@@ -1683,26 +1686,7 @@ class ServeEngine:
         logits, cache = self._launch(
             self._prefill_fn, self.params, jnp.asarray(tokens[None, :])
         )
-        if pad:
-            # end-padding is causally inert for the cached prompt positions
-            # (decode masks by pos), but prefill's returned logits sit at a
-            # pad position.  Re-derive the first token's logits with one
-            # decode step of the last prompt token at its true position; keep
-            # the *prefill* cache verbatim (the decode's KV rewrite of pos
-            # n-1 is the same value only up to low-precision rounding).
-            fix_cache = {
-                "pos": jnp.asarray([n - 1], jnp.int32),
-                "segments": cache["segments"],
-            }
-            logits, _ = self._launch(
-                self._fixup_fn, self.params,
-                jnp.asarray(req.prompt[-1:][None, :]), fix_cache,
-            )
-        req_key = np.asarray(jax.random.fold_in(self._base_key, req.uid))
-        tok = self._sample_token(np.asarray(logits, np.float32)[0], req_key, 0)
-        req.generated.append(int(tok))
-        self._slot_key[slot] = req_key
-        self._slot_tok[slot] = tok
+        self._first_token(slot, req, logits, cache["segments"], pad=bool(pad))
         if self.paged:
             if self._cache is None:
                 self._cache = {
@@ -1775,29 +1759,13 @@ class ServeEngine:
             staging = jax.tree.map(
                 lambda sp: jnp.zeros(sp.shape, sp.dtype), specs
             )
-        prefix_kv = paged_mod.gather_pages(
-            self._cache["segments"], np.asarray(shared, np.int64)
-        )
-        staging = paged_mod.scatter_rows(staging, prefix_kv, 0, self.page_size)
+        staging = self._copy_prefix(staging, shared, req.uid)
         cache = {"pos": jnp.asarray(srows, jnp.int32), "segments": staging}
         logits, cache = self._launch(
             self._chunk_fn, self.params,
             jnp.asarray(tokens[None, srows:b]), cache, start=srows,
         )
-        if b > n:
-            fix_cache = {
-                "pos": jnp.asarray([n - 1], jnp.int32),
-                "segments": cache["segments"],
-            }
-            logits, _ = self._launch(
-                self._fixup_fn, self.params,
-                jnp.asarray(req.prompt[-1:][None, :]), fix_cache,
-            )
-        req_key = np.asarray(jax.random.fold_in(self._base_key, req.uid))
-        tok = self._sample_token(np.asarray(logits, np.float32)[0], req_key, 0)
-        req.generated.append(int(tok))
-        self._slot_key[slot] = req_key
-        self._slot_tok[slot] = tok
+        self._first_token(slot, req, logits, cache["segments"], pad=b > n)
         # all launches done — now mutate allocator/table state (FaultError
         # above this line leaves the engine untouched)
         n_store = paged_mod.pages_for(n, self.page_size)
@@ -1846,6 +1814,8 @@ class ServeEngine:
 
     def _start_chunked(self, slot: int, req: Request) -> None:
         """Admit ``req`` into ``slot`` as a chunked prefill."""
+        if req.admit_t is None:
+            req.admit_t = self.clock.now()
         n = len(req.prompt)
         b = self._bucket_len(n) if self.bucket_prompts else n
         tokens = np.pad(req.prompt, (0, b - n)) if b > n else req.prompt
@@ -1877,12 +1847,7 @@ class ServeEngine:
             # seed the staging rows the shared pages cover; chunking then
             # starts at srows and never recomputes them — same bytes, fewer
             # launches (see _prefill_shared for why this is bit-exact)
-            prefix_kv = paged_mod.gather_pages(
-                self._cache["segments"], np.asarray(shared, np.int64)
-            )
-            staging = paged_mod.scatter_rows(
-                staging, prefix_kv, 0, self.page_size
-            )
+            staging = self._copy_prefix(staging, shared, req.uid)
         self._prefilling[slot] = _Prefilling(
             req=req, tokens=tokens, n=n, chunk=self._chunk_for_new(req),
             cache={"pos": jnp.asarray(srows, jnp.int32), "segments": staging},
@@ -1941,24 +1906,8 @@ class ServeEngine:
         """Prompt fully prefilled: derive token 0 exactly as the unchunked
         path would, then move the request into the decode batch."""
         req, n = entry.req, entry.n
-        pad = len(entry.tokens) - n
-        if pad:
-            # the last chunk's logits sit at a pad position — same fixup as
-            # the unchunked path: one decode step of the last prompt token
-            # at its true position, keeping the prefill cache verbatim
-            fix_cache = {
-                "pos": jnp.asarray([n - 1], jnp.int32),
-                "segments": entry.cache["segments"],
-            }
-            logits, _ = self._launch(
-                self._fixup_fn, self.params,
-                jnp.asarray(req.prompt[-1:][None, :]), fix_cache,
-            )
-        req_key = np.asarray(jax.random.fold_in(self._base_key, req.uid))
-        tok = self._sample_token(np.asarray(logits, np.float32)[0], req_key, 0)
-        req.generated.append(int(tok))
-        self._slot_key[slot] = req_key
-        self._slot_tok[slot] = tok
+        self._first_token(slot, req, logits, entry.cache["segments"],
+                          pad=len(entry.tokens) > n)
         if not self.paged:
             if self._cache is None:
                 self._cache = {
@@ -1990,6 +1939,45 @@ class ServeEngine:
         if req.first_token_t is None:
             self._first_this_step.append(req)
 
+    def _copy_prefix(self, staging, shared: list[int], uid: int):
+        """``staging`` with its first rows seeded from the ``shared`` pool
+        pages, through host memory."""
+        with ledger_mod.span("engine.prefix_copy", uid=uid):
+            prefix_kv = paged_mod.gather_pages(
+                self._cache["segments"], np.asarray(shared, np.int64),
+                ledger=self.ledger,
+            )
+            return paged_mod.scatter_rows(staging, prefix_kv, 0, self.page_size)
+
+    def _first_token(self, slot: int, req: Request, logits, segments, *,
+                     pad: bool) -> None:
+        """Sample token 0 of ``req`` into ``slot`` from its prefill's last
+        ``logits``.  With a padded prompt those logits sit at a pad position
+        (end-padding is causally inert for the cached prompt positions:
+        decode masks by pos), so one decode step of the last prompt token at
+        its true position re-derives them; the prefill cache ``segments`` is
+        kept verbatim (the decode's KV rewrite of pos n-1 is the same value
+        only up to low-precision rounding)."""
+        with ledger_mod.span("engine.first_token", uid=req.uid):
+            if pad:
+                fix_cache = {
+                    "pos": jnp.asarray([len(req.prompt) - 1], jnp.int32),
+                    "segments": segments,
+                }
+                logits, _ = self._launch(
+                    self._fixup_fn, self.params,
+                    jnp.asarray(req.prompt[-1:][None, :]), fix_cache,
+                )
+            req_key, logits = ledger_mod.host_sync(
+                self.ledger,
+                (jax.random.fold_in(self._base_key, req.uid), logits),
+            )
+            tok = self._sample_token(np.asarray(logits, np.float32)[0],
+                                     req_key, 0)
+            req.generated.append(int(tok))
+            self._slot_key[slot] = req_key
+            self._slot_tok[slot] = tok
+
     def _sample_token(self, logits: np.ndarray, req_key: np.ndarray,
                       t: int) -> int:
         """Sample token ``t`` of one request from its position-indexed key.
@@ -2002,9 +1990,9 @@ class ServeEngine:
         if self.temperature <= 0:
             return int(np.argmax(logits))
         sub = jax.random.fold_in(jnp.asarray(req_key), t)
-        return int(jax.random.categorical(
+        return int(ledger_mod.host_sync(self.ledger, jax.random.categorical(
             sub, jnp.asarray(logits) / self.temperature
-        ))
+        )))
 
     # -- fused multi-token decode -------------------------------------------------
 
@@ -2024,6 +2012,7 @@ class ServeEngine:
             return fn
         model, temp, paged = self.model, self.temperature, self.paged
 
+        @jax.named_scope("sample")
         def sample(logits, keys, counts):
             if temp > 0:
                 sub = jax.vmap(jax.random.fold_in)(keys, counts)
@@ -2142,63 +2131,22 @@ class ServeEngine:
 
         Returns requests completed this step.
         """
-        with self._lock:
+        with ledger_mod.span("engine.step"), self._lock:
+            if self.ledger is not None:
+                self.ledger.record_engine_step()
             return self._step_locked()
 
     def _step_locked(self) -> list[Request]:
         self._first_this_step = []
-        # integrity: draw this step's corruption injections on the pre-step
-        # state, then spend the scrub budget — detections park their owners
-        # before any launch can read the bad bytes
-        self._inject_corruption()
-        self._scrub_step()
-        chunked = self.chunk_policy is not None
-        prefill_tokens = 0
-        for slot in range(self.slots):
-            if slot in self._active or slot in self._prefilling:
-                continue
-            if self.paged and self._parked:
-                # parked requests were admitted before anything still queued
-                # (admission is FIFO), so they also resume first — and an
-                # unresumable head blocks younger work exactly like the
-                # queue head does.  A failed attempt is a no-op: the entry
-                # stays parked until pages free up, never spins.
-                if not self._try_resume(self._parked[0], slot):
-                    break
-                continue
-            if not self._queue:
-                break
-            if self.paged:
-                head = self._queue[0]
-                admitted = (self._admit_chunked(head) if chunked
-                            else self._admit_paged(head))
-                if not admitted:
-                    # head-of-line blocking is deliberate: skipping ahead to
-                    # smaller requests would starve large ones forever
-                    break
-            req = self._queue.pop(0)
-            if chunked:
-                self._start_chunked(slot, req)
-            else:
-                try:
-                    self._prefill_slot(slot, req)
-                except FaultError as e:
-                    if self.retry is None:
-                        raise
-                    # the prefill launch faulted before any engine state was
-                    # touched: the request simply goes back to the queue head
-                    # (FIFO preserved) for the next step, or fails on budget
-                    req.fault_recoveries += 1
-                    if req.fault_recoveries > self.retry.max_request_recoveries:
-                        self._fail_request(req, e)
-                    else:
-                        self._queue.insert(0, req)
-                    continue
-                prefill_tokens += (self._bucket_len(len(req.prompt))
-                                   if self.bucket_prompts else len(req.prompt))
-                self._active[slot] = req
-                if req.first_token_t is None:
-                    self._first_this_step.append(req)
+        if self.faults is not None or self.integrity is not None:
+            # integrity: draw this step's corruption injections on the
+            # pre-step state, then spend the scrub budget — detections park
+            # their owners before any launch can read the bad bytes
+            with ledger_mod.span("engine.integrity"):
+                self._inject_corruption()
+                self._scrub_step()
+        with ledger_mod.span("engine.admit"):
+            prefill_tokens = self._admit_locked()
 
         # -- chunk phase: one prefill chunk per prefilling slot, oldest
         # first (uid order), so under page pressure the senior prefill funds
@@ -2209,10 +2157,10 @@ class ServeEngine:
                 key=lambda s: self._prefilling[s].req.uid,
             )
             for slot in order:
+                entry = self._prefilling[slot]
                 try:
-                    prefill_tokens += self._chunk_step(
-                        slot, self._prefilling[slot]
-                    )
+                    with ledger_mod.span("engine.chunk", uid=entry.req.uid):
+                        prefill_tokens += self._chunk_step(slot, entry)
                 except FaultError as e:
                     if self.retry is None:
                         raise
@@ -2273,146 +2221,209 @@ class ServeEngine:
         self._record_memory()
         return finished
 
+    def _admit_locked(self) -> int:
+        """Resume parked requests and admit queued ones into free slots;
+        the prompt rows the whole-prompt path prefilled."""
+        chunked = self.chunk_policy is not None
+        prefill_tokens = 0
+        for slot in range(self.slots):
+            if slot in self._active or slot in self._prefilling:
+                continue
+            if self.paged and self._parked:
+                # parked requests were admitted before anything still queued
+                # (admission is FIFO), so they also resume first — and an
+                # unresumable head blocks younger work exactly like the
+                # queue head does.  A failed attempt is a no-op: the entry
+                # stays parked until pages free up, never spins.
+                entry = self._parked[0]
+                with ledger_mod.span("engine.resume", uid=entry.req.uid):
+                    resumed = self._try_resume(entry, slot)
+                if not resumed:
+                    break
+                continue
+            if not self._queue:
+                break
+            if self.paged:
+                head = self._queue[0]
+                admitted = (self._admit_chunked(head) if chunked
+                            else self._admit_paged(head))
+                if not admitted:
+                    # head-of-line blocking is deliberate: skipping ahead to
+                    # smaller requests would starve large ones forever
+                    break
+            req = self._queue.pop(0)
+            if chunked:
+                self._start_chunked(slot, req)
+            else:
+                try:
+                    with ledger_mod.span("engine.prefill", uid=req.uid):
+                        self._prefill_slot(slot, req)
+                except FaultError as e:
+                    if self.retry is None:
+                        raise
+                    # the prefill launch faulted before any engine state was
+                    # touched: the request simply goes back to the queue head
+                    # (FIFO preserved) for the next step, or fails on budget
+                    req.fault_recoveries += 1
+                    if req.fault_recoveries > self.retry.max_request_recoveries:
+                        self._fail_request(req, e)
+                    else:
+                        self._queue.insert(0, req)
+                    continue
+                prefill_tokens += (self._bucket_len(len(req.prompt))
+                                   if self.bucket_prompts else len(req.prompt))
+                self._active[slot] = req
+                if req.first_token_t is None:
+                    self._first_this_step.append(req)
+        return prefill_tokens
+
     #: decode tokens of the last fused launch (k × live slots) — the decode
     #: half of the step_time_model charge, reset by the step loop
     _decode_tokens_last = 0
 
     def _decode_locked(self) -> list[Request]:
-        k = self._choose_fusion()
-        if self.paged:
-            # fund this launch's on-demand growth first: under overcommit
-            # (growth_reserve < 1) the pool can run dry mid-decode, and the
-            # answer is a shallower launch, then preemption — never
-            # PagePoolExhausted
-            k = self._fund_growth(k)
-            if not self._active:
-                return []                   # every live slot became a victim
-            # re-cap to the survivors: if the longest-remaining slot was
-            # parked, a depth-k scan past every survivor's budget would run
-            # all-masked decode steps (growth stays funded — it was budgeted
-            # for the larger k)
-            k = max(1, min(k, max(
-                r.max_new_tokens - len(r.generated)
-                for r in self._active.values()
-            )))
-        n_live = len(self._active)          # post-preemption: slots decoding
-        self._last_fusion_k = k
-        self._decode_tokens_last = k * n_live
-        self._concurrency_sum += n_live
-        self._concurrency_n += 1
-        self.peak_concurrency = max(self.peak_concurrency, n_live)
-        counts = np.zeros(self.slots, np.int32)
-        remaining = np.zeros(self.slots, np.int32)
-        active = np.zeros(self.slots, bool)
-        for slot, req in self._active.items():
-            self._slot_tok[slot] = req.generated[-1]
-            counts[slot] = len(req.generated)
-            remaining[slot] = req.max_new_tokens - len(req.generated)
-            active[slot] = remaining[slot] > 0
-            if self.paged and remaining[slot] > 0:
-                # on-demand growth, launch-granular: map through the last
-                # position this launch can write for the slot (funded above)
-                self._grow_to(slot, self._launch_pages(slot, req, k))
-        # integrity: the sealed pages this launch will read, captured at
-        # pre-launch positions — decode writes only the unsealed tail, so
-        # any post-launch digest mismatch on these is silent corruption
-        sealed_before: dict[int, list[int]] = {}
-        if self.paged:
-            sealed_before = {
-                slot: self._sealed_pages(slot, int(self._pos[slot]))
-                for slot in self._active
-            }
-        tbl = self._table if self.paged else None
-        if self.paged and self._prefilling:
-            # a mid-prefill slot already has real pages mapped, but it is not
-            # in this launch's active set — its masked dummy writes must land
-            # on the scratch page (as an unmapped slot's would), not on the
-            # chunk rows already scattered into the pool
-            tbl = tbl.copy()
-            for pslot in self._prefilling:
-                tbl[pslot] = paged_mod.TRASH_PAGE
-        table = jnp.asarray(tbl) if self.paged else None
-        # per-slot positions: continuous batching — slots joined at different
-        # times decode against their own sequence positions
-        try:
-            segments, pos, tok, toks, valid = self._launch(
-                self._fused_decode_fn(k), self.params, self._cache["segments"],
-                table, jnp.asarray(self._pos, jnp.int32),
-                jnp.asarray(self._slot_tok),
+        with ledger_mod.span("engine.decode.prep"):
+            k = self._choose_fusion()
+            if self.paged:
+                # fund this launch's on-demand growth first: under
+                # overcommit (growth_reserve < 1) the pool can run dry
+                # mid-decode, and the answer is a shallower launch, then
+                # preemption — never PagePoolExhausted
+                k = self._fund_growth(k)
+                if not self._active:
+                    return []               # every live slot became a victim
+                # re-cap to the survivors: if the longest-remaining slot
+                # was parked, a depth-k scan past every survivor's budget
+                # would run all-masked decode steps (growth stays funded —
+                # it was budgeted for the larger k)
+                k = max(1, min(k, max(
+                    r.max_new_tokens - len(r.generated)
+                    for r in self._active.values()
+                )))
+            n_live = len(self._active)      # post-preemption: slots decoding
+            self._last_fusion_k = k
+            self._decode_tokens_last = k * n_live
+            self._concurrency_sum += n_live
+            self._concurrency_n += 1
+            self.peak_concurrency = max(self.peak_concurrency, n_live)
+            counts = np.zeros(self.slots, np.int32)
+            remaining = np.zeros(self.slots, np.int32)
+            active = np.zeros(self.slots, bool)
+            for slot, req in self._active.items():
+                self._slot_tok[slot] = req.generated[-1]
+                counts[slot] = len(req.generated)
+                remaining[slot] = req.max_new_tokens - len(req.generated)
+                active[slot] = remaining[slot] > 0
+                if self.paged and remaining[slot] > 0:
+                    # on-demand growth, launch-granular: map through the
+                    # last position this launch can write for the slot
+                    # (funded above)
+                    self._grow_to(slot, self._launch_pages(slot, req, k))
+            # integrity: the sealed pages this launch will read, captured
+            # at pre-launch positions — decode writes only the unsealed
+            # tail, so any post-launch digest mismatch on these is silent
+            # corruption
+            sealed_before: dict[int, list[int]] = {}
+            if self.paged:
+                sealed_before = {
+                    slot: self._sealed_pages(slot, int(self._pos[slot]))
+                    for slot in self._active
+                }
+            tbl = self._table if self.paged else None
+            if self.paged and self._prefilling:
+                # a mid-prefill slot already has real pages mapped, but it
+                # is not in this launch's active set — its masked dummy
+                # writes must land on the scratch page (as an unmapped
+                # slot's would), not on the chunk rows already scattered
+                # into the pool
+                tbl = tbl.copy()
+                for pslot in self._prefilling:
+                    tbl[pslot] = paged_mod.TRASH_PAGE
+            # per-slot positions: continuous batching — slots joined at
+            # different times decode against their own sequence positions
+            args = (
+                self.params, self._cache["segments"],
+                jnp.asarray(tbl) if self.paged else None,
+                jnp.asarray(self._pos, jnp.int32), jnp.asarray(self._slot_tok),
                 jnp.asarray(self._slot_key), jnp.asarray(counts),
                 jnp.asarray(active), jnp.asarray(remaining),
             )
+        try:
+            with ledger_mod.span("engine.decode.launch"):
+                segments, pos, tok, toks, valid = self._launch(
+                    self._fused_decode_fn(k), *args)
         except FaultError as e:
             self._recover_decode_fault(e)
             return []
-        # -- pre-commit read verification: re-hash the sealed pages this
-        # launch read against their stamped digests in the *new* segments.
-        # A mismatch parks the owner at its pre-launch state before the
-        # wholesale position/token commit below — corrupt bytes never
-        # influence a committed token, which is what makes the zero-escape
-        # assertion structural rather than probabilistic ---------------------
-        if self.paged:
-            verify = (self.integrity is not None
-                      and self.integrity.verify_reads)
-            corrupt_slots: dict[int, list[int]] = {}
-            for slot in list(self._active):
-                bad: list[int] = []
-                for p in sealed_before.get(slot, ()):
-                    if verify and p in self._page_digests:
-                        if (paged_mod.page_digest(segments, p)
-                                != self._page_digests[p]):
-                            bad.append(p)
-                    elif p in self._live_corrupt_pages:
-                        # verification off: this launch consumed known-bad
-                        # bytes — the token about to commit is divergent
-                        self._live_corrupt_pages.pop(p)
-                        self._record_escape()
-                if bad:
-                    corrupt_slots[slot] = bad
-            if corrupt_slots:
-                all_bad = sorted(
-                    {p for b in corrupt_slots.values() for p in b}
-                )
-                self._handle_corrupt_pages(all_bad, via="read")
-        self._cache = {"segments": segments}
-        self._pos = np.asarray(pos, np.int64)
-        self._slot_tok = np.asarray(tok, np.int32).copy()
-        toks = np.asarray(toks)                      # [k, slots]
-        valid = np.asarray(valid)                    # [k, slots]
-
-        finished = []
-        for slot, req in list(self._active.items()):
-            if slot in self._tainted_slots:
-                # restored from a corrupted payload with verification off:
-                # the stream is divergent from the first post-restore commit
-                self._tainted_slots.discard(slot)
-                self._record_escape()
-            req.generated.extend(int(t) for t in toks[valid[:, slot], slot])
-            if req.replay is not None:
-                # re-prefill resume in flight: the regenerated stream must
-                # match the committed tokens bit for bit — this is the
-                # bitwise-identity claim, checked live, every launch
-                n = min(len(req.generated), len(req.replay))
-                if req.generated[:n] != req.replay[:n]:
-                    raise RuntimeError(
-                        f"preemption replay diverged at request {req.uid}: "
-                        f"regenerated {req.generated[:n]} != committed "
-                        f"{req.replay[:n]}"
+        with ledger_mod.span("engine.decode.commit"):
+            # -- pre-commit read verification: re-hash the sealed pages
+            # this launch read against their stamped digests in the *new*
+            # segments.  A mismatch parks the owner at its pre-launch state
+            # before the wholesale position/token commit below — corrupt
+            # bytes never influence a committed token, which is what makes
+            # the zero-escape assertion structural rather than probabilistic
+            if self.paged:
+                verify = (self.integrity is not None
+                          and self.integrity.verify_reads)
+                corrupt_slots: dict[int, list[int]] = {}
+                for slot in list(self._active):
+                    bad: list[int] = []
+                    for p in sealed_before.get(slot, ()):
+                        if verify and p in self._page_digests:
+                            if (paged_mod.page_digest(segments, p)
+                                    != self._page_digests[p]):
+                                bad.append(p)
+                        elif p in self._live_corrupt_pages:
+                            # verification off: this launch consumed known-bad
+                            # bytes — the token about to commit is divergent
+                            self._live_corrupt_pages.pop(p)
+                            self._record_escape()
+                    if bad:
+                        corrupt_slots[slot] = bad
+                if corrupt_slots:
+                    all_bad = sorted(
+                        {p for b in corrupt_slots.values() for p in b}
                     )
-                if len(req.generated) >= len(req.replay):
-                    req.replay = None          # fully replayed: normal decode
-            if len(req.generated) >= req.max_new_tokens:
-                req.done = True
-                finished.append(req)
-                if self.paged:
-                    self._release_slot(slot, req)
-                del self._active[slot]
-        # stamp digests on pages this launch filled (write boundary: decode
-        # page-crossing commit) — survivors only; finished slots released
-        if self.paged and self.integrity is not None:
-            for slot in self._active:
-                self._seal_slot_pages(slot, int(self._pos[slot]))
-        return finished
+                    self._handle_corrupt_pages(all_bad, via="read")
+            self._cache = {"segments": segments}
+            pos, tok, toks, valid = ledger_mod.host_sync(
+                self.ledger, (pos, tok, toks, valid))  # toks, valid [k, slots]
+            self._pos = pos.astype(np.int64)
+            self._slot_tok = tok.astype(np.int32)
+
+            finished = []
+            for slot, req in list(self._active.items()):
+                if slot in self._tainted_slots:
+                    # restored from a corrupted payload with verification off:
+                    # the stream is divergent from the first post-restore commit
+                    self._tainted_slots.discard(slot)
+                    self._record_escape()
+                req.generated.extend(int(t) for t in toks[valid[:, slot], slot])
+                if req.replay is not None:
+                    # re-prefill resume in flight: the regenerated stream must
+                    # match the committed tokens bit for bit — this is the
+                    # bitwise-identity claim, checked live, every launch
+                    n = min(len(req.generated), len(req.replay))
+                    if req.generated[:n] != req.replay[:n]:
+                        raise RuntimeError(
+                            f"preemption replay diverged at request {req.uid}: "
+                            f"regenerated {req.generated[:n]} != committed "
+                            f"{req.replay[:n]}"
+                        )
+                    if len(req.generated) >= len(req.replay):
+                        req.replay = None      # fully replayed: normal decode
+                if len(req.generated) >= req.max_new_tokens:
+                    req.done = True
+                    finished.append(req)
+                    if self.paged:
+                        self._release_slot(slot, req)
+                    del self._active[slot]
+            # stamp digests on pages this launch filled (write boundary: decode
+            # page-crossing commit) — survivors only; finished slots released
+            if self.paged and self.integrity is not None:
+                for slot in self._active:
+                    self._seal_slot_pages(slot, int(self._pos[slot]))
+            return finished
 
     def run_to_completion(self, max_steps: int = 10_000) -> list[Request]:
         """Step until every submitted request finishes; the completed requests.

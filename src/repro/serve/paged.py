@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.ledger import host_sync
+
 TRASH_PAGE = 0
 
 
@@ -424,8 +426,9 @@ class PrefixIndex:
 # ---------------------------------------------------------------------------
 
 
-def gather_pages(pool_segments, pages: np.ndarray):
-    """Copy ``pages`` of every pool leaf to host memory.
+def gather_pages(pool_segments, pages: np.ndarray, *, ledger=None):
+    """Copy ``pages`` of every pool leaf to host memory, one host sync per
+    leaf counted in ``ledger``.
 
     Returns a tree of numpy arrays ``[L, n, Hkv, page_size, hd]`` — the
     victim's KV exactly as it sits in the pool.  The copy is bit-preserving
@@ -434,7 +437,8 @@ def gather_pages(pool_segments, pages: np.ndarray):
     anything.
     """
     idx = jnp.asarray(pages, jnp.int32)
-    return jax.tree.map(lambda pool: np.asarray(pool[:, idx]), pool_segments)
+    return host_sync(ledger,
+                     jax.tree.map(lambda pool: pool[:, idx], pool_segments))
 
 
 def restore_pages(pool_segments, saved, pages: np.ndarray):

@@ -18,7 +18,6 @@ The acceptance bars (ISSUE PR-6):
 
 import math
 import threading
-import time
 
 import jax
 import pytest
@@ -185,18 +184,32 @@ def test_traffic_split_matches_hand_computed_oracle(engine_model):
 def test_midflight_submit_wallclock_feeder_thread(engine_model):
     """submit() from a feeder thread while run_to_completion is mid-flight:
     the late requests are admitted at a step boundary and finish — never
-    lost, never misclassified as rejected.  The first step's jit compile
-    spans hundreds of ms, so a 50 ms feeder delay lands safely mid-flight."""
+    lost, never misclassified as rejected.  "Mid-flight" is an event, not a
+    sleep: the feeder submits once the engine has completed its first step,
+    and that step returns to run_to_completion only after the feeder has
+    submitted, so the submits land between steps whatever a step costs."""
     _, model, params = engine_model
     eng = ServeEngine(model, params, batch_slots=2, max_len=64,
                       decode_fusion=2, prefill_chunk=4)
     first = [eng.submit(p, max_new_tokens=12) for p in PROMPTS[:2]]
     late: list[int] = []
+    stepped, fed = threading.Event(), threading.Event()
+    engine_step = eng.step
+
+    def step():
+        done = engine_step()
+        if not stepped.is_set():
+            stepped.set()
+            assert fed.wait(timeout=60), "the feeder never submitted"
+        return done
+
+    eng.step = step
 
     def feeder():
-        time.sleep(0.05)
+        assert stepped.wait(timeout=600), "the engine never stepped"
         for p in PROMPTS[2:]:
             late.append(eng.submit(p, max_new_tokens=4))
+        fed.set()
 
     th = threading.Thread(target=feeder)
     th.start()
