@@ -125,7 +125,7 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(table_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref,
                   *, scale: float, page_size: int, n_pages: int) -> None:
     """Same online softmax as :func:`_dec_kernel`, but the KV tile streamed at
@@ -175,6 +175,7 @@ def paged_decode_attention(
     block_table: jax.Array,         # [B, NP] int32 page index -> pool page
     length,                         # scalar or [B] valid cache lengths
     *,
+    layer=None,
     scale: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -182,9 +183,13 @@ def paged_decode_attention(
     table: grid (B, Hkv, NP), the page axis innermost, each KV tile DMA'd
     straight from its (non-contiguous) pool page.  Unlike the gather-based
     XLA formulation, no dense [B, Hkv, T, hd] copy is ever materialized in
-    HBM — the gather happens on the HBM→VMEM stream."""
+    HBM — the gather happens on the HBM→VMEM stream.  With ``layer`` the
+    pools are stacks [L, P, Hkv, ps, hd]; the layer index is prefetched
+    with the table and picks the stack's layer in the same index maps."""
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     B, Hq, hd = q.shape
-    Hkv, ps = k_pages.shape[1], k_pages.shape[2]
+    Hkv, ps = k_pages.shape[2], k_pages.shape[3]
     NP = block_table.shape[1]
     assert Hq % Hkv == 0
     group = Hq // Hkv
@@ -195,20 +200,27 @@ def paged_decode_attention(
         lengths = jnp.broadcast_to(lengths, (B,))
     lengths = lengths.astype(jnp.int32)
     table = block_table.astype(jnp.int32)
+    layers = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     qg = q.reshape(B, Hkv, group, hd)
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, page_size=ps, n_pages=NP
     )
+    kv_spec = pl.BlockSpec(
+        (None, 1, 1, ps, hd),
+        lambda b, h, i, tab, ln, ly: (ly[0], tab[b, i], h, 0, 0),
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                    # block table + lengths
+        num_scalar_prefetch=3,                    # block table, lengths, layer
         grid=(B, Hkv, NP),                        # page axis innermost
         in_specs=[
-            pl.BlockSpec((1, 1, group, hd), lambda b, h, i, tab, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, ps, hd), lambda b, h, i, tab, ln: (tab[b, i], h, 0, 0)),
-            pl.BlockSpec((1, 1, ps, hd), lambda b, h, i, tab, ln: (tab[b, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, group, hd),
+                         lambda b, h, i, tab, ln, ly: (b, h, 0, 0)),
+            kv_spec,
+            kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, group, hd), lambda b, h, i, tab, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, group, hd),
+                               lambda b, h, i, tab, ln, ly: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
@@ -220,7 +232,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, hd), q.dtype),
         interpret=interpret,
-    )(table, lengths, qg, k_pages, v_pages)
+    )(table, lengths, layers, qg, k_pages, v_pages)
     return out.reshape(B, Hq, hd)
 
 

@@ -223,7 +223,7 @@ REG.register(
 
 
 def xla_paged_decode_attention(q, k_pages, v_pages, block_table, length, *,
-                               scale=None):
+                               layer=None, scale=None):
     """Gather-then-dense formulation: ``jnp.take`` reassembles the sequence's
     pages into the dense [B, Hkv, T, hd] layout, then the grouped-GQA dense
     decode attention runs unchanged.  Because the gather is arithmetic-free
@@ -231,9 +231,11 @@ def xla_paged_decode_attention(q, k_pages, v_pages, block_table, length, *,
     bitwise-identical to :func:`xla_decode_attention` over an equivalent
     dense cache — the property the paged serving engine's equivalence
     guarantee rests on.  (The Pallas kernel instead resolves pages on the
-    HBM→VMEM stream and never materializes the dense copy.)"""
-    kg = ref.gather_kv_pages(k_pages, block_table)
-    vg = ref.gather_kv_pages(v_pages, block_table)
+    HBM→VMEM stream and never materializes the dense copy.)  With
+    ``layer`` the pools are stacks [L, P, Hkv, ps, hd], gathered at
+    ``(layer, block_table)`` so the layer's pool is never sliced out."""
+    kg = ref.gather_kv_pages(k_pages, block_table, layer)
+    vg = ref.gather_kv_pages(v_pages, block_table, layer)
     return xla_decode_attention(q, kg, vg, length, scale=scale)
 
 

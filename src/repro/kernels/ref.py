@@ -142,7 +142,8 @@ def ssd(
     return y
 
 
-def gather_kv_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
+def gather_kv_pages(pages: jax.Array, block_table: jax.Array,
+                    layer: jax.Array | None = None) -> jax.Array:
     """Reassemble a per-sequence dense KV view from a paged pool.
 
     ``pages`` [P, Hkv, ps, D] is the global block pool; ``block_table``
@@ -150,10 +151,15 @@ def gather_kv_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
     [B, Hkv, NP*ps, D] holds position ``t`` of sequence ``b`` at
     ``[b, :, t]`` — exactly the dense cache layout, so any dense decode
     attention runs unchanged (and bitwise-identically) on the gather.
+    With ``layer``, ``pages`` is a stack of pools [L, P, Hkv, ps, D] and
+    layer ``layer``'s pages are gathered straight from the stack.
     """
     B, NP = block_table.shape
-    _, Hkv, ps, D = pages.shape
-    out = jnp.take(pages, block_table, axis=0)           # [B, NP, Hkv, ps, D]
+    Hkv, ps, D = pages.shape[-3:]
+    if layer is None:
+        out = jnp.take(pages, block_table, axis=0)       # [B, NP, Hkv, ps, D]
+    else:
+        out = pages[layer, block_table]
     return out.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, NP * ps, D)
 
 
@@ -164,16 +170,18 @@ def paged_decode_attention(
     block_table: jax.Array,         # [B, NP] page index -> pool page
     length: jax.Array | int,        # valid cache length (scalar or [B])
     *,
+    layer: jax.Array | None = None,
     scale: float | None = None,
 ) -> jax.Array:
     """One-token attention over a paged KV cache: gather, then dense oracle.
 
     Positions ``>= length`` (page tails, unmapped table entries pointing at
     the reserved scratch page) are masked before the softmax, so their
-    contents never reach the output.
+    contents never reach the output.  With ``layer`` the pools are stacks
+    [L, P, Hkv, ps, D] and layer ``layer`` is read.
     """
-    kg = gather_kv_pages(k_pages, block_table)
-    vg = gather_kv_pages(v_pages, block_table)
+    kg = gather_kv_pages(k_pages, block_table, layer)
+    vg = gather_kv_pages(v_pages, block_table, layer)
     return decode_attention(q, kg, vg, length, scale=scale)
 
 

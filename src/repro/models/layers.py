@@ -349,26 +349,34 @@ def _decode_out(p: Params, out: jax.Array) -> jax.Array:
     return y[:, None, :]
 
 
-def paged_write_kv(pool: jax.Array, new: jax.Array, page: jax.Array,
-                   offset: jax.Array) -> jax.Array:
-    """Write one token's KV [B, H, hd] into the pool [P, H, ps, hd] at each
-    sequence's ``(page[b], offset[b])``.
+def paged_write_kv(pool: jax.Array, new: jax.Array, layer: jax.Array,
+                   page: jax.Array, offset: jax.Array) -> jax.Array:
+    """Write one token's KV [B, H, hd] into layer ``layer`` of the stacked
+    pool [L, P, H, ps, hd] at each sequence's ``(page[b], offset[b])``.
 
-    Live slots own disjoint pages, so batch writes never collide; masked
-    (finished) slots are steered to the scratch page by their cleared block
-    tables, where collisions are harmless.
+    One scatter into the whole stack, so a pool carried through a loop is
+    updated in place.  Each (sequence, head) row is its own scatter index:
+    a window of one ``hd`` row fits the pool's default layout, where a
+    window of all heads' rows makes the TPU compiler relay the pool out
+    (and copy it whole at the program's edges).  Live slots own disjoint
+    pages, so batch writes never collide; masked (finished) slots are
+    steered to the scratch page by their cleared block tables, where
+    collisions are harmless.
     """
-    return pool.at[page, :, offset].set(new.astype(pool.dtype))
+    heads = jnp.arange(new.shape[1])[None, :]
+    return pool.at[layer, page[:, None], heads, offset[:, None]].set(
+        new.astype(pool.dtype))
 
 
 def attention_decode_paged(
     p: Params,
     x: jax.Array,                      # [B, 1, d]
-    k_pages: jax.Array,                # [P, Hkv, ps, hd] global block pool
-    v_pages: jax.Array,
+    k_pool: jax.Array,                 # [L, P, Hkv, ps, hd] stacked block pools
+    v_pool: jax.Array,
     block_table: jax.Array,            # [B, NP] page index -> pool page
     pos: jax.Array,                    # scalar or [B]: tokens already cached
     cfg: ArchConfig,
+    layer: jax.Array,                  # scalar: this layer's index in the stack
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Single-token decode against a paged KV cache.
 
@@ -378,23 +386,27 @@ def attention_decode_paged(
     ``paged_decode_attention`` op (whose XLA source gathers pages back into
     the dense layout and then executes the same dense decode-attention
     function — which is what makes paged serving bitwise-identical to
-    dense, not merely allclose).
+    dense, not merely allclose).  The pools hold every layer of a segment
+    and are returned whole: the write touches one row per sequence and
+    attention reads layer ``layer``'s pages through the block table, so
+    neither copies the layer's pool.
     """
     B = x.shape[0]
-    ps = k_pages.shape[2]
+    ps = k_pool.shape[3]
     q, k, v = _decode_qkv(p, x, pos, cfg)
     posb = jnp.broadcast_to(pos, (B,)) if pos.ndim == 0 else pos
     with jax.named_scope("attn.kv_write"):
         page = jnp.take_along_axis(
             block_table, (posb // ps)[:, None], axis=1
         )[:, 0]
-        k_pages = paged_write_kv(k_pages, k, page, posb % ps)
-        v_pages = paged_write_kv(v_pages, v, page, posb % ps)
+        k_pool = paged_write_kv(k_pool, k, layer, page, posb % ps)
+        v_pool = paged_write_kv(v_pool, v, layer, page, posb % ps)
     with jax.named_scope("attn.core"):
         out = dispatch.op(
-            "paged_decode_attention", q, k_pages, v_pages, block_table, posb + 1
+            "paged_decode_attention", q, k_pool, v_pool, block_table,
+            posb + 1, layer=layer,
         )
-    return _decode_out(p, out), k_pages, v_pages
+    return _decode_out(p, out), k_pool, v_pool
 
 
 def cross_attention_specs(cfg: ArchConfig) -> Params:

@@ -282,13 +282,14 @@ def _window_clip(cfg: ArchConfig, kv: dict) -> dict:
 
 
 def apply_layer_decode(cfg, kind, p, x, cache, pos, moe_info=None,
-                       block_table=None):
+                       block_table=None, layer=None):
     """One-token step. Returns (x, new_cache).
 
-    With ``block_table`` the layer's k/v leaves are page *pools* ([P, Hkv,
-    page_size, hd]) shared by the whole batch, and attention routes through
-    the paged write + block-table kernel.  Only plain position-indexed GQA
-    caches support paging; recurrent / latent / windowed layouts raise.
+    With ``block_table`` the k/v leaves are the segment's stacked page
+    *pools* ([L, P, Hkv, page_size, hd]) shared by the whole batch, this
+    layer's being ``[layer]``, and attention routes through the paged write
+    + block-table kernel.  Only plain position-indexed GQA caches support
+    paging; recurrent / latent / windowed layouts raise.
     """
     if block_table is not None and (kind not in ("dense", "moe")
                                     or cfg.mla is not None
@@ -327,7 +328,7 @@ def apply_layer_decode(cfg, kind, p, x, cache, pos, moe_info=None,
         new_cache.update({"ckv": ckv, "krope": krope})
     elif block_table is not None:
         a, pk, pv = layers.attention_decode_paged(
-            p["attn"], h, cache["k"], cache["v"], block_table, pos, cfg
+            p["attn"], h, cache["k"], cache["v"], block_table, pos, cfg, layer
         )
         new_cache.update({"k": pk, "v": pv})
     else:
@@ -633,7 +634,10 @@ class DecoderLM:
         attention layers to the paged KV path: k/v leaves of ``segments``
         are then global page pools, written and read through the table (see
         :mod:`repro.serve.paged`).  The table itself is engine-owned and not
-        part of the returned cache.
+        part of the returned cache.  The layer scan then carries each
+        segment's stacked pools instead of scanning them: a token's K/V is
+        written into the stack in place, where slicing out each layer's pool
+        and restacking it would copy the whole pool every step.
         """
         cfg = self.cfg
         h = layers.embed_tokens(params["embed"], tokens)
@@ -644,6 +648,28 @@ class DecoderLM:
         for seg, seg_params, seg_cache in zip(
             self.segments, params["segments"], cache["segments"]
         ):
+            if block_table is not None:
+                def body(carry, xs, _seg=seg):
+                    x, pools = carry
+                    dt0 = x.dtype
+                    unit_params, layer = xs
+                    pools = dict(pools)
+                    for i, kind in enumerate(_seg.kinds):
+                        x, pools[str(i)] = apply_layer_decode(
+                            cfg, kind, unit_params[str(i)], x, pools[str(i)],
+                            pos, moe_info=moe_info, block_table=block_table,
+                            layer=layer,
+                        )
+                        x = shard_act(x.astype(dt0), "batch", None, None)
+                    return (x, pools), None
+
+                (h, new_seg), _ = _scan(
+                    body, (h, seg_cache), (seg_params, jnp.arange(seg.count)),
+                    remat="none", unroll=self.unroll,
+                )
+                new_segs.append(new_seg)
+                continue
+
             def body(carry, xs, _seg=seg):
                 x = carry
                 dt0 = x.dtype
@@ -652,7 +678,7 @@ class DecoderLM:
                 for i, kind in enumerate(_seg.kinds):
                     x, c = apply_layer_decode(
                         cfg, kind, unit_params[str(i)], x, unit_cache[str(i)],
-                        pos, moe_info=moe_info, block_table=block_table,
+                        pos, moe_info=moe_info,
                     )
                     x = shard_act(x.astype(dt0), "batch", None, None)
                     new_unit[str(i)] = c

@@ -2049,7 +2049,10 @@ class ServeEngine:
             return segments, pos, tok, toks, valid
 
         fused.__name__ = f"decode_fused_k{k}" + ("_paged" if paged else "")
-        fn = jax.jit(fused)
+        # the page pool is donated: the launch updates it in place and the
+        # engine keeps only the returned pool (faults are drawn before a
+        # packet runs, so a faulted launch leaves the donated pool intact)
+        fn = jax.jit(fused, donate_argnums=(1,) if paged else ())
         fn.__name__ = fused.__name__
         self._fused_cache[k] = fn
         return fn
@@ -2355,6 +2358,8 @@ class ServeEngine:
         except FaultError as e:
             self._recover_decode_fault(e)
             return []
+        # the launch consumed the donated pool: only the returned one is live
+        self._cache = {"segments": segments}
         with ledger_mod.span("engine.decode.commit"):
             # -- pre-commit read verification: re-hash the sealed pages
             # this launch read against their stamped digests in the *new*
@@ -2385,7 +2390,6 @@ class ServeEngine:
                         {p for b in corrupt_slots.values() for p in b}
                     )
                     self._handle_corrupt_pages(all_bad, via="read")
-            self._cache = {"segments": segments}
             pos, tok, toks, valid = ledger_mod.host_sync(
                 self.ledger, (pos, tok, toks, valid))  # toks, valid [k, slots]
             self._pos = pos.astype(np.int64)

@@ -1,5 +1,7 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracles."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -323,6 +325,30 @@ def test_pallas_paged_decode_attention_sweep(dtype, hq, hkv, ps, n_pages):
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
     )
+
+
+@pytest.mark.parametrize("source", ["reference", "xla", "pallas"])
+def test_paged_decode_attention_reads_layer_of_stack(source):
+    """With ``layer`` each source reads that layer of a stacked pool [L, P,
+    Hkv, ps, hd] — bitwise what it reads from the layer's own pool."""
+    B, Hq, Hkv, ps, n_pages, D, L = 2, 8, 2, 8, 4, 32, 3
+    fn = {
+        "reference": ref.paged_decode_attention,
+        "xla": ops.xla_paged_decode_attention,
+        "pallas": functools.partial(pallas_paged_decode, interpret=True),
+    }[source]
+    q = _rand((B, Hq, D), jnp.bfloat16)
+    cases = [_paged_case(B, Hkv, ps, n_pages, B * n_pages + 1, D,
+                         jnp.bfloat16, seed=0) for _ in range(L)]
+    k_stack = jnp.stack([c[0] for c in cases])
+    v_stack = jnp.stack([c[1] for c in cases])
+    _, _, table, lengths = cases[0]
+    for layer in range(L):
+        got = jax.jit(lambda *a: fn(*a[:5], layer=a[5]))(
+            q, k_stack, v_stack, table, lengths, jnp.int32(layer))
+        want = fn(q, cases[layer][0], cases[layer][1], table, lengths)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 def test_paged_gather_is_bitwise_dense():

@@ -13,8 +13,11 @@ import pytest
 import jax
 
 from repro.configs import ARCHS, reduced
+from repro.core.hsa import FaultPlan, Queue, Scheduler, VirtualClock
 from repro.core.ledger import OverheadLedger
-from repro.core.policy import AdmissionPolicy, PreemptionPolicy
+from repro.core.policy import AdmissionPolicy, PreemptionPolicy, RetryPolicy
+from repro.core.reconfig import RegionManager
+from repro.core.roles import RoleLibrary
 from repro.models import build_model
 from repro.models.params import init_params
 from repro.serve.engine import ServeEngine, ServeTruncated
@@ -54,19 +57,65 @@ def _generate(model, params, *, paged, fusion=1, temperature=0.0, slots=2,
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("fusion", [1, 4])
-def test_paged_greedy_bitwise_identical(engine_model, fusion):
+# one page of shared system prompt, then a distinct user turn
+SHARED_PROMPTS = [[9, 8, 7, 6, 5, 4, 3, 2] + s
+                  for s in ([1], [2, 3], [4, 5, 6], [7])]
+
+
+def _shared_spill_fault_kw():
+    """Engine options for a paged run under stress: prefix sharing,
+    overcommitted admission into a pool too small for both slots (page
+    growth parks a victim, whose snapshot spills to the host arena), and
+    one injected fault on the first fused decode launch, recovered by
+    re-prefill replay through the HSA queue."""
+    led = OverheadLedger()
+    plan = FaultPlan()
+    plan.force("exec", "decode_fused")
+    sched = Scheduler(RegionManager(4, ledger=led), RoleLibrary(ledger=led),
+                      ledger=led, clock=VirtualClock(), faults=plan)
+    queue = sched.add_queue(Queue(None, 256, name="serve"))
+    return dict(prefix=True, pool_pages=4, hsa_queue=queue,
+                hsa_scheduler=sched, ledger=led, retry=RetryPolicy(),
+                admission=AdmissionPolicy(growth_reserve=0.25),
+                preemption=PreemptionPolicy(snapshot_threshold_tokens=0))
+
+
+@pytest.mark.parametrize("fusion,stress", [
+    pytest.param(1, False, id="1"),
+    pytest.param(3, False, id="3"),
+    pytest.param(4, False, id="4"),
+    pytest.param(4, True, id="4-prefix-spill-fault"),
+])
+def test_paged_greedy_bitwise_identical(engine_model, fusion, stress):
     _, model, params = engine_model
-    dense, _ = _generate(model, params, paged=False, fusion=fusion)
-    paged, eng = _generate(model, params, paged=True, fusion=fusion)
+    prompts = SHARED_PROMPTS if stress else PROMPTS
+    kw = _shared_spill_fault_kw() if stress else {}
+    dense, _ = _generate(model, params, paged=False, fusion=fusion,
+                         prompts=prompts)
+    paged, eng = _generate(model, params, paged=True, fusion=fusion,
+                           prompts=prompts, **kw)
     assert paged == dense
     assert all(len(g) == 7 for g in paged)
     # every page back in the pool the moment serving drained
     eng.allocator.check_invariants()
     assert eng.allocator.free_pages == eng.allocator.total_pages
+    if stress:                  # each stress actually happened
+        assert eng.prefix_hits > 0
+        assert eng.preemptions > 0 and eng.spills > 0
+        avail = eng.ledger.availability_split()
+        assert avail["faults"] == 1 and avail["recoveries"] >= 1
+    # the fused decode takes the pool by donation and updates it in place:
+    # the pool a launch was handed is gone once it returns
+    eng.submit(PROMPTS[0], max_new_tokens=7)
+    eng.step()                              # prefill (and a first launch)
+    before = jax.tree.leaves(eng._cache["segments"])
+    eng.step()                              # a decode launch
+    assert all(leaf.is_deleted() for leaf in before)
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree.leaves(eng._cache["segments"]))
 
 
-@pytest.mark.parametrize("fusion", [1, 4])
+@pytest.mark.parametrize("fusion", [1, 3, 4])
 def test_paged_temperature_bitwise_identical(engine_model, fusion):
     """Seeded temperature sampling must survive paging at any fusion depth —
     the draw depends only on (seed, uid, logits), and paged logits are

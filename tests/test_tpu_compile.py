@@ -13,7 +13,9 @@ this file.  All cases stay in this one file so one worker holds it.
 
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +35,7 @@ from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssd import ssd
 from repro.models import build_model
 from repro.models.params import abstract_params
+from repro.serve.engine import ServeEngine
 
 YI = get_arch("yi-6b")
 HD, HQ, HKV = YI.head_dim, YI.num_heads, YI.num_kv_heads      # 128, 32, 4
@@ -161,6 +164,63 @@ def test_yi6b_decode_step_compiles(one_chip, prefer, paged):
     sources = {name.split(":")[0] for _, name in trace.events}
     assert _has_kernel(compiled) == (prefer == "pallas"), sources
     assert compiled.memory_analysis() is not None
+
+
+_COPY_OPS = re.compile(
+    r"^\s*(?:ROOT )?\S+ = (.*?) "
+    r"(copy|copy-start|dynamic-update-slice|dynamic-slice)\(%"
+)
+
+
+def _pool_copies(hlo: str, layer_shape: tuple[int, ...]) -> list[str]:
+    """Copies and (dynamic) slices or slice updates in ``hlo`` whose result
+    holds a whole layer of a pool leaf or more: an array ending in the
+    layer's shape ``(P, Hkv, ps, hd)``."""
+    out = []
+    for line in hlo.splitlines():
+        m = _COPY_OPS.match(line)
+        if m is None:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1)):
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            if shape[-len(layer_shape):] == layer_shape:
+                out.append(line.strip()[:160])
+    return out
+
+
+@pytest.mark.parametrize("prefer", ["xla", "pallas"])
+def test_yi6b_fused_paged_decode_updates_pool_in_place(one_chip, prefer):
+    """The engine's fused K=4 paged decode at Yi-6B widths (2 layers, 12
+    slots, ``max_len`` 4096, page 16): the pool is donated and aliased to
+    the output, and no copy, dynamic-slice or dynamic-update-slice holds a
+    pool leaf or one layer of one."""
+    cfg = dataclasses.replace(YI, num_layers=2)
+    model = build_model(cfg)
+    shard = lambda s: _spec(s.shape, s.dtype, one_chip)   # noqa: E731
+    params = jax.tree.map(shard, abstract_params(model.param_specs()))
+    slots, page_size = 12, 16
+    eng = ServeEngine(model, params, batch_slots=slots, max_len=T,
+                      paged=True, page_size=page_size, decode_fusion=4)
+    segments = jax.tree.map(
+        lambda s: _spec((s.shape[0], eng.pool_pages, *s.shape[2:]), s.dtype,
+                        one_chip),
+        model.cache_specs(1, page_size)["segments"],
+    )
+    per_slot = lambda dtype: _spec((slots,), dtype, one_chip)   # noqa: E731
+    fn = eng._fused_decode_fn(4)
+    with dispatch.use(prefer=dispatch.policy_from_flag(prefer)):
+        compiled = fn.lower(
+            params, segments,
+            _spec((slots, T // page_size), jnp.int32, one_chip),
+            per_slot(jnp.int32), per_slot(jnp.int32),
+            _spec((slots, 2), jnp.uint32, one_chip),
+            per_slot(jnp.int32), per_slot(jnp.bool_), per_slot(jnp.int32),
+        ).compile()
+    assert fn.__name__ == "decode_fused_k4_paged"
+    leaves = jax.tree.leaves(segments)
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    assert _pool_copies(compiled.as_text(), leaves[0].shape[1:]) == []
 
 
 # Mosaic refuses these two kernels; they are off the serving path.  The PR
