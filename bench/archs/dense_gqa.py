@@ -1,13 +1,15 @@
-"""Plain float32 reference of a dense GQA decoder, and its fp8 control.
+"""Dense grouped-query-attention decoder, the architecture module
+(``bench.spec``) of configurations with ``"arch": "dense_gqa"``: its
+weights, float32 reference, fp8 control and work counts.
 
-Written from the published layer equations (pre-norm RMSNorm blocks,
-half-split rotary embeddings, causal grouped-query attention, SwiGLU MLP,
-final RMSNorm, untied or tied unembedding), in ``jax.numpy`` with every
-matmul at the highest precision.  It imports nothing of the program: it
-reads the weight tree that ``bench.weights`` draws.  The benchmark keeps
-its own copy so that the yardstick cannot move with the program; the
-program's own reference (``repro.models.reference``) computes the same
-function.
+The reference is written from the published layer equations (pre-norm
+RMSNorm blocks, half-split rotary embeddings, causal grouped-query
+attention, SwiGLU MLP, final RMSNorm, untied or tied unembedding), in
+``jax.numpy`` with every matmul at the highest precision.  It imports
+nothing of the program: it reads the weight tree that ``bench.weights``
+draws.  The benchmark keeps its own copy so that the yardstick cannot move
+with the program; the program's own reference (``repro.models.reference``)
+computes the same function.
 
 ``mode="fp8"`` is the control: every matmul input (weights per tensor,
 activations per row) is rounded to float8 e4m3 with a scale that maps its
@@ -21,13 +23,60 @@ attention and the unembedding run in blocks of rows, so a sequence of
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from bench import spec
+
+FAMILY = "dense"
 BLOCK = 512          # rows per attention / unembedding block
 E4M3_MAX = 448.0
+
+
+def layout(cfg: dict) -> dict:
+    """``{path: (shape, init, scale)}`` of every weight, keyed as the
+    program's parameter tree nests them (``/``-joined)."""
+    L, d, f, V = cfg["num_layers"], cfg["d_model"], cfg["d_ff"], cfg["vocab_size"]
+    q, kv = cfg["num_heads"] * cfg["head_dim"], cfg["num_kv_heads"] * cfg["head_dim"]
+
+    def lin(a, b):
+        return ((L, a, b), "normal", 1.0 / np.sqrt(a))
+
+    out = {
+        "embed/tok": ((V, d), "normal", 0.02),
+        "ln_f": ((d,), "ones", 0.0),
+        "segments/0/0/ln1": ((L, d), "ones", 0.0),
+        "segments/0/0/ln2": ((L, d), "ones", 0.0),
+        "segments/0/0/attn/wq": lin(d, q),
+        "segments/0/0/attn/wk": lin(d, kv),
+        "segments/0/0/attn/wv": lin(d, kv),
+        "segments/0/0/attn/wo": lin(q, d),
+        "segments/0/0/mlp/wg": lin(d, f),
+        "segments/0/0/mlp/wu": lin(d, f),
+        "segments/0/0/mlp/wd": lin(f, d),
+    }
+    if not cfg["tie_embeddings"]:
+        out["embed/unembed"] = ((d, V), "normal", 1.0 / np.sqrt(d))
+    return out
+
+
+def nest(flat: dict) -> dict:
+    """The program's tree of the ``/``-joined ``flat`` leaves: one stacked
+    segment of ``num_layers`` dense layers."""
+    tree: dict = {}
+    for path, leaf in flat.items():
+        node = tree
+        parts = path.split("/")
+        for p in parts[:-1]:
+            node = node.setdefault(p, {})
+        node[parts[-1]] = leaf
+    tree["segments"] = [tree["segments"]["0"]]
+    return tree
 
 
 def _fp8(x, axis=None):
@@ -120,10 +169,10 @@ def _logit_blocks(cfg, mode, params, h, fn):
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _gaps(cfg_items, params, tokens, targets):
+def _gaps(cfg_key, params, tokens, targets):
     """Per row: the reference's best logit minus its logit of ``targets``
     (the next token served at that row)."""
-    cfg = dict(cfg_items)
+    cfg = json.loads(cfg_key)
     with jax.default_matmul_precision("highest"):
         h = _hidden(cfg, "f32", params, tokens)
 
@@ -136,20 +185,13 @@ def _gaps(cfg_items, params, tokens, targets):
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _control_tokens(cfg_items, params, tokens):
+def _control_tokens(cfg_key, params, tokens):
     """The token the fp8 control puts first at each row."""
-    cfg = dict(cfg_items)
+    cfg = json.loads(cfg_key)
     with jax.default_matmul_precision("highest"):
         h = _hidden(cfg, "fp8", params, tokens)
         return _logit_blocks(cfg, "fp8", params, h,
                              lambda logits, i: jnp.argmax(logits, axis=-1))
-
-
-REF_KEYS = ("num_heads", "num_kv_heads", "head_dim", "norm_eps", "rope_theta")
-
-
-def _items(cfg: dict) -> tuple:
-    return tuple((k, cfg[k]) for k in REF_KEYS)
 
 
 def padded(tokens, length: int):
@@ -161,11 +203,83 @@ def padded(tokens, length: int):
 def served_gaps(cfg: dict, params, sequence, targets, length: int):
     """Reference gaps of ``targets`` at every row of ``sequence`` (both
     padded to ``length``); a float32 array of ``length`` rows."""
-    return _gaps(_items(cfg), params, padded(sequence, length),
+    return _gaps(spec.model_key(cfg), params, padded(sequence, length),
                  padded(targets, length))
 
 
 def control_gaps(cfg: dict, params, sequence, length: int):
     """Reference gaps of the tokens the fp8 control puts first."""
-    toks = _control_tokens(_items(cfg), params, padded(sequence, length))
-    return _gaps(_items(cfg), params, padded(sequence, length), toks)
+    toks = _control_tokens(spec.model_key(cfg), params, padded(sequence, length))
+    return _gaps(spec.model_key(cfg), params, padded(sequence, length), toks)
+
+
+@dataclasses.dataclass(frozen=True)
+class Shapes:
+    """Work per token and layer (``bench.counts``): matmuls
+    ``2 * (d*H*hd + 2*d*Hkv*hd + H*hd*d + 3*d*f)`` FLOPs, attention over
+    ``c`` rows ``4 * H * hd * c``, one row's logits ``2 * d * V``."""
+
+    layers: int
+    d_model: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    d_ff: int
+    vocab: int
+    tied: bool
+    dtype_bytes: int = 2
+
+    @classmethod
+    def of(cls, cfg: dict) -> "Shapes":
+        return cls(cfg["num_layers"], cfg["d_model"], cfg["num_heads"],
+                   cfg["num_kv_heads"], cfg["head_dim"], cfg["d_ff"],
+                   cfg["vocab_size"], cfg["tie_embeddings"],
+                   2 if cfg["dtype"] in ("bfloat16", "float16") else 4)
+
+    # -- parameters ----------------------------------------------------------
+
+    @property
+    def layer_params(self) -> int:
+        d, q, kv = self.d_model, self.heads * self.head_dim, self.kv_heads * self.head_dim
+        return d * q + 2 * d * kv + q * d + 3 * d * self.d_ff + 2 * d
+
+    @property
+    def layer_bytes(self) -> int:
+        """Weights of every layer."""
+        return self.layers * self.layer_params * self.dtype_bytes
+
+    @property
+    def head_bytes(self) -> int:
+        """The final norm and the unembedding (the embedding rows a step
+        gathers are a rounding error)."""
+        return (self.d_model * self.vocab + self.d_model) * self.dtype_bytes
+
+    @property
+    def weight_bytes(self) -> int:
+        """Weights one decode step reads."""
+        return self.layer_bytes + self.head_bytes
+
+    @property
+    def kv_bytes_per_row(self) -> int:
+        """K and V of one context row, all layers."""
+        return 2 * self.layers * self.kv_heads * self.head_dim * self.dtype_bytes
+
+    # -- FLOPs ----------------------------------------------------------------
+
+    @property
+    def matmul_flops(self) -> int:
+        """Per token: every layer's projections and MLP."""
+        d, q, kv = self.d_model, self.heads * self.head_dim, self.kv_heads * self.head_dim
+        return self.layers * 2 * (d * q + 2 * d * kv + q * d + 3 * d * self.d_ff)
+
+    @property
+    def head_flops(self) -> int:
+        """One row's logits."""
+        return 2 * self.d_model * self.vocab
+
+    def attention_flops(self, context_rows: int) -> int:
+        """Per token attending to ``context_rows`` rows, all layers."""
+        return self.layers * 4 * self.heads * self.head_dim * context_rows
+
+
+shapes = Shapes.of

@@ -3,18 +3,17 @@ decides ``correct``.
 
 After the window, a sample drawn from the seed of the requests it
 completed, the longest among them, is run once through the float32
-reference (``bench.reference``) over each prompt and the tokens served
-for it.  At every served position the reference's best logit minus its
-logit of the token served is that token's gap; greedy decoding that
-computes what the reference computes serves gaps of rounding size only.
+reference of the cell's architecture module (``served_gaps`` of
+``bench/archs/<a>.py``) over each prompt and the tokens served for it.
+At every served position the reference's best logit minus its logit of
+the token served is that token's gap; greedy decoding that computes what
+the reference computes serves gaps of rounding size only.
 The number compared is the widest gap over the sample.
 """
 
 from __future__ import annotations
 
 import numpy as np
-
-from bench import reference
 
 
 def sample(records, seed: int, target_tokens: int, max_requests: int) -> list:
@@ -44,25 +43,26 @@ def rows(record) -> tuple[np.ndarray, np.ndarray, slice]:
     return full[:-1], full[1:], slice(n - 1, len(full) - 1)
 
 
-def widest_gap(cfg: dict, params, records, length: int) -> tuple[float, int]:
+def widest_gap(arch, cfg: dict, params, records, length: int) -> tuple[float, int]:
     """``(widest gap, tokens compared)`` of the served tokens of
-    ``records``; each sequence is padded to ``length`` rows."""
+    ``records`` under architecture module ``arch``; each sequence is
+    padded to ``length`` rows."""
     worst, count = 0.0, 0
     for r in records:
         seq, nxt, served = rows(r)
-        gaps = np.asarray(reference.served_gaps(cfg, params, seq, nxt, length))
+        gaps = np.asarray(arch.served_gaps(cfg, params, seq, nxt, length))
         worst = max(worst, float(gaps[served].max()))
         count += served.stop - served.start
     return worst, count
 
 
-def control_gap(cfg: dict, params, records, length: int) -> float:
+def control_gap(arch, cfg: dict, params, records, length: int) -> float:
     """The widest gap of the tokens the fp8 control puts first at the same
     positions: what the number compared reads when the model is computed
     one precision step below the one it states."""
     worst = 0.0
     for r in records:
         seq, _, served = rows(r)
-        gaps = np.asarray(reference.control_gaps(cfg, params, seq, length))
+        gaps = np.asarray(arch.control_gaps(cfg, params, seq, length))
         worst = max(worst, float(gaps[served].max()))
     return worst

@@ -3,12 +3,14 @@
 
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Builds the cell's model with weights drawn from ``--seed``, sets up
+Builds the cell's model, with the architecture module its configuration
+names (``bench/archs/<a>.py``) and weights drawn from ``--seed``, sets up
 ``ServeEngine`` as users get it by default (paged KV, chunked prefill,
 prefix sharing, fused decode, every launch on the HSA queue of the default
 agent), warms up every shape the cell's traffic uses, then serves the
 traffic for ``--seconds`` seconds and drains what the window left running.
-Afterwards it checks the served tokens against the float32 reference.
+Afterwards it checks the served tokens against that module's float32
+reference.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
@@ -22,6 +24,7 @@ cell asks for, it exits non-zero and prints no result.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import gc
 import json
 import os
@@ -76,19 +79,20 @@ def enable_compile_cache() -> str:
     return path
 
 
-def build_model(cfg: dict):
+def build_model(cfg: dict, arch):
+    """The program's model of ``cfg``: every key of its ``model`` is passed
+    to ``ArchConfig``, with ``family`` from the architecture module."""
+    from bench import spec
     from repro.configs.base import ArchConfig
     from repro.models import build_model as build
 
-    arch = ArchConfig(
-        name=cfg["name"], family="dense", num_layers=cfg["num_layers"],
-        d_model=cfg["d_model"], num_heads=cfg["num_heads"],
-        num_kv_heads=cfg["num_kv_heads"], d_ff=cfg["d_ff"],
-        vocab_size=cfg["vocab_size"], head_dim=cfg["head_dim"],
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["norm_eps"],
-        tie_embeddings=cfg["tie_embeddings"], dtype=cfg["dtype"],
-    )
-    return build(arch)
+    model = spec.model(cfg)
+    fields = {f.name for f in dataclasses.fields(ArchConfig)} - {"name", "family"}
+    unknown = sorted(set(model) - fields)
+    if unknown:
+        raise ValueError(f"configuration {cfg['name']!r}: the program's ArchConfig "
+                         f"has no field {', '.join(unknown)}")
+    return build(ArchConfig(name=cfg["name"], family=arch.FAMILY, **model))
 
 
 def check_layout(model, params) -> None:
@@ -158,11 +162,11 @@ class Serving:
         self.cell, self.seed, sv = cell, seed, cell.serving
         self.warmed: set[int] = set()
         self.compiles = CompileCounter()
-        self.model = build_model(cell.config)
-        params = weights.make(cell.config, seed)
+        self.model = build_model(cell.config, cell.arch)
+        params = weights.make(cell.arch, cell.config, seed)
         check_layout(self.model, params)
         jax.block_until_ready(params)
-        log(f"weights: {weights.nbytes(cell.config) / 1e9:.3f} GB drawn, "
+        log(f"weights: {weights.nbytes(cell.arch, cell.config) / 1e9:.3f} GB drawn, "
             f"{time.time() - T_PROCESS:.1f} s after process start")
         hsa_shut_down()
         self.system = hsa_init(num_regions=2, ledger=OverheadLedger())
@@ -278,11 +282,11 @@ def describe(window, stats) -> None:
 
 
 def per_layer(cell, window, stats, reduced, device_kind: str, memory: dict) -> dict:
-    from bench import counts, spec
+    from bench import spec
     from bench.peaks import peaks
 
     flops, bw = peaks(device_kind)
-    ctx = Context(cfg=cell.config, serving=cell.serving, shapes=counts.Shapes.of(cell.config),
+    ctx = Context(cfg=cell.config, serving=cell.serving, shapes=cell.arch.shapes(cell.config),
                   window=window, reduced=reduced, memory=memory,
                   peak_flops=flops, peak_bw=bw, **{k: v for k, v in stats.items()
                                                    if k != "setup_s"})
@@ -304,8 +308,8 @@ def correctness(cell, seed: int, window, *, control: bool = False) -> dict:
     recs = window.records
     done = [r for r in recs if r.done]
     picked = check.sample(recs, seed, sv["check_tokens"], sv["check_requests"])
-    params = weights.make(cfg, seed)
-    gap, compared = (check.widest_gap(cfg, params, picked, sv["max_len"])
+    params = weights.make(cell.arch, cfg, seed)
+    gap, compared = (check.widest_gap(cell.arch, cfg, params, picked, sv["max_len"])
                      if picked else (float("inf"), 0))
     out = {
         "logit_gap": {"value": gap, "limit": cfg["check"]["logit_gap_limit"]},
@@ -315,7 +319,8 @@ def correctness(cell, seed: int, window, *, control: bool = False) -> dict:
                                        for r in done), "limit": 0},
     }
     if control and picked:
-        out["control_gap"] = {"value": check.control_gap(cfg, params, picked, sv["max_len"]),
+        out["control_gap"] = {"value": check.control_gap(cell.arch, cfg, params, picked,
+                                                         sv["max_len"]),
                               "limit": cfg["check"]["logit_gap_limit"]}
     log(f"reference over {len(picked)} requests ({compared} served tokens): "
         f"{time.time() - t0:.1f} s")

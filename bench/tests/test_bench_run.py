@@ -5,7 +5,8 @@ the seed, the engine on the HSA queue, warm-up, an open-loop window, the
 drain, the per-layer readers and the check against the float32
 reference) runs as on the chip.  The check must pass on the program as it
 is, and must fail when a token is altered where the fused decode produces
-it, and when the fp8 control is put in the program's place.
+it, when the fp8 control is put in the program's place, and when the
+configuration names an architecture module whose reference is wrong.
 """
 
 from __future__ import annotations
@@ -27,7 +28,7 @@ SEED = 2**33 + 5
 TINY = {"num_layers": 2, "d_model": 128, "num_heads": 4, "num_kv_heads": 1,
         "head_dim": 32, "d_ff": 256, "vocab_size": 512, "rope_theta": 10000.0,
         "norm_eps": 1e-5, "tie_embeddings": False, "dtype": "bfloat16",
-        "name": "tiny",
+        "name": "tiny", "arch": "dense_gqa",
         # the tiny model's own limit, between its readings on the CPU (widest
         # gap at most 0.030 over nine seeds) and the fp8 control's (at least
         # 0.19 on the same samples; see test_fp8_control_fails_the_check)
@@ -43,11 +44,12 @@ SERVING = {**spec.ENGINE_DEFAULTS, "batch_slots": 4, "max_len": 512,
 
 def _cell():
     bm = spec.benchmark(ROOT)
-    return spec.Cell("tiny", 1, TINY, MIX, SERVING, bm["end_to_end"], bm["per_layer"])
+    return spec.Cell("tiny", 1, TINY, spec.arch(TINY["arch"]), MIX, SERVING,
+                     bm["end_to_end"], bm["per_layer"])
 
 
-def _run(tmp_path, trace=False):
-    return run.run_cell(_cell(), SEED, 2.0, trace, device_kind="TPU v5 lite",
+def _run(tmp_path, trace=False, cell=None):
+    return run.run_cell(cell or _cell(), SEED, 2.0, trace, device_kind="TPU v5 lite",
                         trace_dir=tmp_path / "trace", control=True)
 
 
@@ -138,12 +140,12 @@ def test_reference_matches_the_program_reference():
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import reference
     from repro.models import reference as program_ref
 
+    reference = spec.arch("dense_gqa")
     cfg = {**TINY, "tie_embeddings": True}
-    params = weights.make(cfg, SEED)
-    arch = run.build_model(cfg).cfg
+    params = weights.make(reference, cfg, SEED)
+    arch = run.build_model(cfg, reference).cfg
     toks = jnp.asarray(np.arange(1, 41) * 7 % 500 + 1, jnp.int32)
     want = np.asarray(program_ref.logits(arch, params, toks))
     targets = jnp.asarray(np.argsort(want, axis=-1)[:, -2].astype(np.int32))
@@ -152,6 +154,63 @@ def test_reference_matches_the_program_reference():
     second = np.take_along_axis(want, np.asarray(targets)[:, None], -1)[:, 0]
     np.testing.assert_allclose(gaps, best - second, rtol=1e-4, atol=1e-5)
     assert jax.devices()[0].platform == "cpu"
+
+
+def _bench_with_arch(tmp_path, name: str, source: str):
+    """A ``bench/`` beside a ``BENCHMARK.json`` whose one cell runs the tiny
+    model under the architecture module ``archs/<name>.py`` (``source``)."""
+    bench = tmp_path / "bench"
+    for d in ("archs", "configs", "traffic", "cells"):
+        (bench / d).mkdir(parents=True)
+    (bench / "archs" / f"{name}.py").write_text(source)
+    model = {k: v for k, v in TINY.items() if k not in spec.HARNESS_KEYS}
+    (bench / "configs" / "tiny.json").write_text(json.dumps(
+        {"arch": name, "model": model, "check": TINY["check"]}))
+    (bench / "traffic" / "tiny_mix.json").write_text(json.dumps(MIX))
+    (bench / "cells" / "tiny.cell.json").write_text(json.dumps(
+        {k: SERVING[k] for k in ("batch_slots", "max_len", "check_tokens",
+                                 "check_requests")}))
+    bm = spec.benchmark(ROOT)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps({
+        **bm, "workloads": [{"name": "tiny.cell", "config": "tiny",
+                             "traffic": "tiny_mix", "chips": 1, "why": "test"}]}))
+    return spec.cell(tmp_path, "tiny.cell", bench=bench)
+
+
+@pytest.mark.parametrize("name, unscaled, correct", [
+    ("dense_copy", False, True),
+    ("dense_unscaled", True, False),
+])
+def test_the_named_module_decides_correct(tmp_path, name, unscaled, correct):
+    """A configuration that names a module new to ``bench/archs/`` runs end
+    to end with no edit to the harness, and its reference is the one the
+    check uses: a copy of ``dense_gqa`` passes, and a copy whose attention
+    drops the 1/sqrt(head_dim) scale fails."""
+    source = (Path(spec.BENCH) / "archs" / "dense_gqa.py").read_text()
+    if unscaled:
+        scale = " / jnp.sqrt(jnp.float32(hd))"
+        assert source.count(scale) == 1
+        source = source.replace(scale, "")
+    cell = _bench_with_arch(tmp_path, name, source)
+    assert cell.arch.__name__ == f"bench_arch_{name}"
+    res = _run(tmp_path, cell=cell)
+    assert res["correct"] is correct
+    gap = res["checks"]["logit_gap"]
+    assert (gap["value"] <= gap["limit"]) is correct
+    assert res["checks"]["unfinished"]["value"] == 0
+
+
+def test_a_key_the_program_lacks_fails_before_weights(monkeypatch):
+    """A model key that ``ArchConfig`` has no field for stops set-up, named,
+    before any weights are drawn: it never runs as another model."""
+    def draw(*args, **kwargs):
+        raise AssertionError("weights drawn")
+
+    monkeypatch.setattr(weights, "make", draw)
+    cell = _cell()
+    cell.config = {**TINY, "logits_scaling": 16.0}
+    with pytest.raises(ValueError, match="logits_scaling"):
+        run.Serving(cell, SEED)
 
 
 

@@ -1,10 +1,13 @@
-"""Configurations, traffic mixes, cells and per-layer metrics are found by
-name: a new one is a new file, with no edit to the harness."""
+"""Configurations, architecture modules, traffic mixes, cells and
+per-layer metrics are found by name: a new one is a new file, with no edit
+to the harness."""
 
 from __future__ import annotations
 
 import json
 from pathlib import Path
+
+import pytest
 
 from bench import spec
 
@@ -13,13 +16,15 @@ ROOT = Path(__file__).resolve().parents[2]
 
 def test_new_files_are_taken_as_data(tmp_path):
     bench = tmp_path / "bench"
-    for d in ("configs", "traffic", "cells", "metrics"):
+    for d in ("archs", "configs", "traffic", "cells", "metrics"):
         (bench / d).mkdir(parents=True)
     model = {"num_layers": 2, "d_model": 64, "num_heads": 2, "num_kv_heads": 1,
              "head_dim": 32, "d_ff": 128, "vocab_size": 100, "rope_theta": 1e4,
              "norm_eps": 1e-5, "tie_embeddings": True, "dtype": "bfloat16"}
-    (bench / "configs" / "new-model.json").write_text(
-        json.dumps({"model": model, "check": {"logit_gap_limit": 0.1}}))
+    (bench / "archs" / "new_arch.py").write_text(
+        "FAMILY = 'new'\n\n\ndef shapes(cfg):\n    return cfg['d_model'] * 3\n")
+    (bench / "configs" / "new-model.json").write_text(json.dumps(
+        {"arch": "new_arch", "model": model, "check": {"logit_gap_limit": 0.1}}))
     (bench / "traffic" / "new_mix.json").write_text(json.dumps({"loop": "open"}))
     (bench / "cells" / "new.cell.json").write_text(
         json.dumps({"batch_slots": 3, "max_len": 512}))
@@ -35,6 +40,9 @@ def test_new_files_are_taken_as_data(tmp_path):
     cell = spec.cell(tmp_path, "new.cell", bench=bench)
     assert cell.config["d_model"] == 64 and cell.config["name"] == "new-model"
     assert cell.config["check"] == {"logit_gap_limit": 0.1}
+    assert cell.config["arch"] == "new_arch"
+    assert cell.arch.FAMILY == "new" and cell.arch.shapes(cell.config) == 192
+    assert spec.model(cell.config) == model
     assert cell.mix == {"loop": "open"}
     assert cell.serving["batch_slots"] == 3
     assert cell.serving["prefill_chunk"] == spec.ENGINE_DEFAULTS["prefill_chunk"]
@@ -60,3 +68,16 @@ def test_every_named_file_exists():
         cell = spec.cell(ROOT, w["name"])
         names = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in names and len(names) >= 2 and cell.per_layer
+
+
+def test_a_configuration_names_its_architecture(tmp_path):
+    """No configuration runs as dense GQA by default: one without ``arch``
+    is refused."""
+    (tmp_path / "configs").mkdir()
+    (tmp_path / "configs" / "bare.json").write_text(json.dumps(
+        {"model": {"d_model": 64}, "check": {"logit_gap_limit": 0.1}}))
+    with pytest.raises(ValueError, match="architecture"):
+        spec.config("bare", bench=tmp_path)
+    for c in spec.benchmark(ROOT)["configs"]:
+        cfg = spec.config(c["name"])
+        assert (spec.BENCH / "archs" / f"{cfg['arch']}.py").is_file()
