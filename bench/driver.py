@@ -29,6 +29,7 @@ class Record:
     first_token: float | None = None
     finish: float | None = None
     generated: list[int] | None = None
+    admit: float | None = None         # engine clock: a slot took it
 
     @property
     def done(self) -> bool:
@@ -43,7 +44,8 @@ class Window:
     seconds: float
     records: list[Record]
     drain_end: float
-    steps: int
+    steps: int                         # engine steps in the window
+    drain_steps: int = 0               # and in the drain after it
 
 
 # ---------------------------------------------------------------------------
@@ -199,16 +201,21 @@ def _step(engine, records: dict) -> list:
         rec = records.get(r.uid)
         if rec is not None:
             rec.first_token, rec.finish = r.first_token_t, r.finish_t
+            rec.admit = r.admit_t
             rec.generated = list(r.generated)
     return done
 
 
-def _drain(engine, records: dict, until: float) -> float:
+def _drain(engine, records: dict, until: float) -> tuple[float, int]:
+    """Step until every record is done or ``until``; the engine clock at
+    the end and the steps taken."""
+    steps = 0
     while any(not r.done for r in records.values()):
         if engine.clock.now() >= until:
             break
         _step(engine, records)
-    return engine.clock.now()
+        steps += 1
+    return engine.clock.now(), steps
 
 
 def run_open(engine, specs: list, seconds: float, drain_s: float) -> Window:
@@ -236,8 +243,8 @@ def run_open(engine, specs: list, seconds: float, drain_s: float) -> Window:
     while i < len(specs) and specs[i].due < seconds:   # due before the close
         _submit(engine, specs[i], t0 + specs[i].due, records)
         i += 1
-    drain_end = _drain(engine, records, end + drain_s)
-    return Window(t0, seconds, list(records.values()), drain_end, steps)
+    drain_end, drained = _drain(engine, records, end + drain_s)
+    return Window(t0, seconds, list(records.values()), drain_end, steps, drained)
 
 
 def run_closed(engine, specs: list, clients: int, seconds: float,
@@ -259,5 +266,5 @@ def run_closed(engine, specs: list, clients: int, seconds: float,
             if q and clock.now() < end:
                 _submit(engine, q.pop(0), clock.now(), records)
         steps += 1
-    drain_end = _drain(engine, records, end + drain_s)
-    return Window(t0, seconds, list(records.values()), drain_end, steps)
+    drain_end, drained = _drain(engine, records, end + drain_s)
+    return Window(t0, seconds, list(records.values()), drain_end, steps, drained)

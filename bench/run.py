@@ -28,9 +28,12 @@ import dataclasses
 import gc
 import json
 import os
+import re
 import shutil
 import sys
 import time
+import typing
+from collections import defaultdict
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -79,16 +82,33 @@ def enable_compile_cache() -> str:
     return path
 
 
+def _group(field_type):
+    """The dataclass among the types of an ``ArchConfig`` field
+    (``MoEConfig`` of ``MoEConfig | None``), or ``None``."""
+    kinds = typing.get_args(field_type) or (field_type,)
+    return next((k for k in kinds if dataclasses.is_dataclass(k)), None)
+
+
 def build_model(cfg: dict, arch):
     """The program's model of ``cfg``: every key of its ``model`` is passed
-    to ``ArchConfig``, with ``family`` from the architecture module."""
+    to ``ArchConfig``, with ``family`` from the architecture module.  A key
+    whose field is a group (``moe``, ``mla``, ``ssm``) holds an object of
+    that group's keys and is built into its dataclass.  A key the program
+    has no field for, at the top or inside a group, is refused by name."""
     from bench import spec
     from repro.configs.base import ArchConfig
     from repro.models import build_model as build
 
     model = spec.model(cfg)
-    fields = {f.name for f in dataclasses.fields(ArchConfig)} - {"name", "family"}
+    types = typing.get_type_hints(ArchConfig)
+    fields = set(types) - {"name", "family"}
     unknown = sorted(set(model) - fields)
+    for key, value in model.items():
+        group = _group(types.get(key))
+        if group is not None and isinstance(value, dict):
+            have = {f.name for f in dataclasses.fields(group)}
+            unknown += sorted(f"{key}.{k}" for k in set(value) - have)
+            model[key] = group(**value) if set(value) <= have else value
     if unknown:
         raise ValueError(f"configuration {cfg['name']!r}: the program's ArchConfig "
                          f"has no field {', '.join(unknown)}")
@@ -136,6 +156,25 @@ def _hsa_stats(ledger) -> dict:
 
     return {c: (ledger.stat(c).count, ledger.stat(c).total_s)
             for c in (L.DISPATCH_SUBMIT, L.DISPATCH_GRANT, L.DISPATCH_WAIT, L.EXEC)}
+
+
+def counters(*ledgers) -> dict[str, float]:
+    """The counters of ``ledgers`` (``OverheadLedger``s), under the
+    ledger's own names and summed over the ledgers, each event being
+    recorded in one of them: every key of ``sync_split()`` but its ratio,
+    and each category's ``<category>.count`` and ``<category>.total_s``."""
+    from repro.core import ledger as L
+
+    out: dict[str, float] = defaultdict(float)
+    for led in ledgers:
+        for k, v in led.sync_split().items():
+            if k != "host_syncs_per_step":
+                out[k] += v
+        for c in L.CATEGORIES:
+            st = led.stat(c)
+            out[f"{c}.count"] += st.count
+            out[f"{c}.total_s"] += st.total_s
+    return dict(out)
 
 
 class Context:
@@ -227,7 +266,9 @@ class Serving:
         from bench import devtrace, driver
 
         eng = self.engine
+        ledgers = (self.engine_log, self.system.ledger)
         c0, hsa0 = self.compiles.snapshot(), _hsa_stats(self.system.ledger)
+        counts0 = counters(*ledgers)
         saved0, n0 = eng.prefix_pages_saved, len(self.engine_log.lookups)
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -244,6 +285,7 @@ class Serving:
         if trace_dir is not None:
             jax.profiler.stop_trace()
         c1, hsa1 = self.compiles.snapshot(), _hsa_stats(self.system.ledger)
+        counts1 = counters(*ledgers)
         lookups = self.engine_log.lookups[n0:]
         stats = {
             "setup_s": setup_s,
@@ -251,8 +293,51 @@ class Serving:
             "prefix_pages_saved": eng.prefix_pages_saved - saved0,
             "lookups": lookups if len(lookups) == len(window.records) else None,
             "hsa": {k: (hsa1[k][0] - hsa0[k][0], hsa1[k][1] - hsa0[k][1]) for k in hsa1},
+            "counters": {k: v - counts0.get(k, 0.0) for k, v in counts1.items()},
         }
         return window, stats
+
+    def fused_decode_op_names(self, trace) -> dict[str, dict[str, str]]:
+        """Instruction -> ``op_name`` of the compiled fused decode programs
+        that ran in ``trace``, for a trace whose operations name none."""
+        import numpy as np
+
+        from bench import devtrace, work
+
+        ks = sorted({int(m.group(1)) for dev in trace.programs for name, _, _ in dev
+                     if (m := re.search(work.DECODE, name))})
+        eng, n = self.engine, self.engine.slots
+        args = (eng.params, eng._cache["segments"], np.asarray(eng._table),
+                np.zeros(n, np.int32), np.zeros(n, np.int32), eng._slot_key,
+                np.zeros(n, np.int32), np.zeros(n, bool), np.zeros(n, np.int32))
+        out = {}
+        for k in ks:
+            fn = eng._fused_decode_fn(k)
+            out[f"jit_{fn.__name__}"] = devtrace.hlo_op_names(
+                fn.lower(*args).compile().as_text())
+        return out
+
+    def load_trace(self, trace_dir):
+        """The trace under ``trace_dir``, its operations named by their
+        ``op_name``: from the trace, else from the compiled fused decode.
+        Call it after the window, with the engine still alive."""
+        from bench import devtrace
+
+        t0, (n0, s0, hits0) = time.time(), self.compiles.snapshot()
+        trace = devtrace.load(str(trace_dir))
+        if any(trace.ops) and not devtrace.named(trace):
+            try:
+                hlo = self.fused_decode_op_names(trace)
+            except (AttributeError, KeyError, TypeError, ValueError) as e:
+                log(f"trace: no op_name on the operations, and the fused decode's "
+                    f"HLO could not be read: {e!r}")
+            else:
+                n, s, hits = self.compiles.snapshot()
+                log(f"trace: no op_name on the operations; read from the compiled "
+                    f"{sorted(hlo)} in {time.time() - t0:.1f} s ({n - n0} executables, "
+                    f"{hits - hits0} from the persistent cache, {s - s0:.1f} s compiling)")
+                trace = devtrace.name_ops(trace, hlo)
+        return trace
 
     def close(self) -> None:
         """Free the program's state (the reference needs the memory)."""
@@ -292,7 +377,7 @@ def per_layer(cell, window, stats, reduced, device_kind: str, memory: dict) -> d
                                                    if k != "setup_s"})
     out = {}
     for m in cell.per_layer:
-        v = spec.metric_reader(m["name"])(ctx)
+        v = spec.metric_reader(m["name"], cell.bench)(ctx)
         if v is not None:
             out[m["name"]] = {"value": v, "unit": m["unit"]}
     return out
@@ -359,7 +444,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, device_kind: str,
               "memory_peak_bytes": int(mem.get("peak_bytes_in_use", 0))}
     if trace:
         t0 = time.time()
-        reduced = devtrace.reduce(devtrace.load(str(trace_dir)))
+        reduced = devtrace.reduce(srv.load_trace(trace_dir))
         log(f"trace read in {time.time() - t0:.1f} s")
         if not keep_trace:
             shutil.rmtree(trace_dir, ignore_errors=True)

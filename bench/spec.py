@@ -1,8 +1,9 @@
 """``BENCHMARK.json`` and the files it names, found by name.
 
 * a configuration ``<c>`` is ``bench/configs/<c>.json``: its ``model``
-  (every key is passed to the program's ``ArchConfig``), its ``check``
-  limits, and ``arch``, the name of its architecture module;
+  (every key is passed to the program's ``ArchConfig``; a group such as
+  ``moe``, ``mla`` or ``ssm`` is an object of its dataclass's keys), its
+  ``check`` limits, and ``arch``, the name of its architecture module;
 * an architecture ``<a>`` is ``bench/archs/<a>.py``: ``FAMILY`` (the
   program's ``ArchConfig.family``), ``layout(cfg)`` and ``nest(flat)`` (the
   weights ``bench.weights`` draws), ``served_gaps`` and ``control_gaps``
@@ -13,7 +14,17 @@
 * a cell ``<w>`` (a workload) keeps its serving sizes in
   ``bench/cells/<w>.json``;
 * a per-layer metric ``<m>`` is read by ``read(ctx)`` in
-  ``bench/metrics/<m>.py``.
+  ``bench/metrics/<m>.py``.  Besides the cell's sizes and the window's
+  request records (``ctx.window``; each ``Record`` has its due, admission,
+  first-token and finish times), a reader may read the reduced device
+  trace (``ctx.reduced``, ``None`` without one: device seconds per program
+  and, in ``scopes[<program>][<scope>]``, by every named scope of its
+  operations, of every program where the trace names its operations'
+  ``op_name``s and of the fused decode alone where it does not, as on the
+  v5e; device idle seconds by the innermost host span in
+  ``idle_by_span``) and the window's ledger counters (``ctx.counters``,
+  keyed by the ledger's own names; see ``bench.run.counters``).  It
+  returns ``None`` where it finds nothing to read.
 
 Adding any of them is adding a file: the harness needs no edit.
 """
@@ -54,6 +65,7 @@ class Cell:
     serving: dict
     end_to_end: list[dict]
     per_layer: list[dict]
+    bench: Path = BENCH               # where its files were found
 
 
 def _json(path: Path) -> dict:
@@ -132,4 +144,5 @@ def cell(root: Path, workload: str, bench: Path = BENCH) -> Cell:
         serving=serving(workload, bench),
         end_to_end=[m for m in bm["end_to_end"] if applies(m)],
         per_layer=[m for m in bm["per_layer"] if applies(m)],
+        bench=bench,
     )

@@ -108,6 +108,16 @@ def test_warm_up_leaves_nothing_to_compile_in_the_window(sound):
         metrics["mfu.prefill"]["value"] + metrics["mfu.decode"]["value"])
 
 
+def test_the_programs_own_readings_reach_the_result(sound):
+    """Admission times and the window's ledger counters reach their readers;
+    the readers of the device trace stay silent on the CPU."""
+    metrics = sound["metrics"]
+    assert 0.0 <= metrics["queue_wait_p95_s"]["value"]
+    assert 0.0 < metrics["prefill_p95_s"]["value"]
+    assert metrics["host_syncs_per_step"]["value"] > 1
+    assert "step_host_gap_ms" not in metrics and "decode_attention_ms" not in metrics
+
+
 def test_fp8_control_fails_the_check(sound):
     control = sound["checks"]["control_gap"]
     assert control["value"] > control["limit"]
@@ -200,17 +210,36 @@ def test_the_named_module_decides_correct(tmp_path, name, unscaled, correct):
     assert res["checks"]["unfinished"]["value"] == 0
 
 
-def test_a_key_the_program_lacks_fails_before_weights(monkeypatch):
-    """A model key that ``ArchConfig`` has no field for stops set-up, named,
-    before any weights are drawn: it never runs as another model."""
+MOE = {"num_experts": 4, "experts_per_token": 2, "d_ff_expert": 64,
+       "num_shared_experts": 1}
+
+
+@pytest.mark.parametrize("extra, named", [
+    ({"logits_scaling": 16.0}, "logits_scaling"),
+    ({"moe": {**MOE, "n_group": 1}}, "moe.n_group"),
+], ids=["top-level", "in-a-group"])
+def test_a_key_the_program_lacks_fails_before_weights(monkeypatch, extra, named):
+    """A model key that ``ArchConfig`` has no field for, at the top or
+    inside a group, stops set-up, named, before any weights are drawn: it
+    never runs as another model."""
     def draw(*args, **kwargs):
         raise AssertionError("weights drawn")
 
     monkeypatch.setattr(weights, "make", draw)
     cell = _cell()
-    cell.config = {**TINY, "logits_scaling": 16.0}
-    with pytest.raises(ValueError, match="logits_scaling"):
+    cell.config = {**TINY, **extra}
+    with pytest.raises(ValueError, match=named):
         run.Serving(cell, SEED)
+
+
+def test_a_group_reaches_the_program_as_its_dataclass():
+    """A ``moe`` group of a configuration's ``model`` is built into the
+    program's ``MoEConfig``, its defaults filled in."""
+    from repro.configs.base import MoEConfig
+
+    model = run.build_model({**TINY, "moe": MOE}, spec.arch(TINY["arch"]))
+    assert model.cfg.moe == MoEConfig(**MOE)
+    assert model.cfg.moe.first_k_dense == 0 and model.cfg.mla is None
 
 
 

@@ -11,7 +11,8 @@ import re
 
 from bench import counts, devtrace
 
-DECODE = r"decode_fused"
+#: a fused decode program, with its depth K
+DECODE = r"decode_fused_k(\d+)"
 PREFILL_CHUNK = r"prefill_chunk"
 
 
@@ -58,7 +59,7 @@ def decode_steps(ctx):
     n = steps = 0
     sec = 0.0
     for name, (k, s) in red.programs.items():
-        m = re.search(DECODE + r"_k(\d+)", name)
+        m = re.search(DECODE, name)
         if m:
             n, steps, sec = n + k, steps + k * int(m.group(1)), sec + s
     return (n, steps, sec) if n else None
@@ -74,3 +75,8 @@ def chunk_time(ctx):
         return None
     n, s = devtrace.launches(ctx.reduced, PREFILL_CHUNK)
     return (n, s) if n else None
+
+
+def engine_steps(ctx) -> int:
+    """Engine steps the driver took in the window and its drain."""
+    return ctx.window.steps + ctx.window.drain_steps
