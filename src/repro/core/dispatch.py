@@ -10,6 +10,12 @@ The active :class:`DispatchContext` selects the device kind and source
 preference.  Flipping ``prefer=("pallas", "xla", "reference")`` retargets an
 entire model to hand-written Pallas roles without touching model code; that
 one-flag switch is the paper's transparency claim.
+
+With no explicit preference, an op named in :data:`PLATFORM_PREFER` is staged
+per platform (``jax.lax.platform_dependent``): the program lowered for that
+platform runs its source, every other platform the default order.  The choice
+is made when the program is lowered, so a TPU program compiled on a CPU host
+(a described topology) gets the TPU's source too.
 """
 
 from __future__ import annotations
@@ -19,13 +25,26 @@ import contextvars
 import dataclasses
 from typing import Any, Callable, Iterator, Sequence
 
+import jax
+
 from repro.core.registry import GLOBAL_REGISTRY, KernelImpl, KernelRegistry
+
+
+#: source order of every op when the context names none
+DEFAULT_PREFER: tuple[str, ...] = ("xla", "reference")
+
+#: ops whose default source depends on the platform the program is lowered
+#: for.  The paged decode attention's XLA source gathers every table page of
+#: every slot; on a TPU the Pallas kernel reads each slot's live pages only.
+PLATFORM_PREFER: dict[str, dict[str, tuple[str, ...]]] = {
+    "paged_decode_attention": {"tpu": ("pallas", "xla", "reference")},
+}
 
 
 @dataclasses.dataclass(frozen=True)
 class DispatchContext:
     device_kind: str = "tpu"
-    prefer: tuple[str, ...] = ("xla", "reference")
+    prefer: tuple[str, ...] | None = None      # None: DEFAULT_PREFER / PLATFORM_PREFER
     registry: KernelRegistry = GLOBAL_REGISTRY
     interpret: bool = False          # forwarded to pallas impls (CPU validation)
     trace: "DispatchTrace | None" = None
@@ -38,14 +57,26 @@ class DispatchContext:
         default_factory=dict, compare=False, repr=False, hash=False
     )
 
-    def resolve(self, op: str, *, specialization: str | None = None) -> KernelImpl:
-        key = (op, specialization)
+    def order(self, op: str, platform: str | None = None) -> tuple[str, ...]:
+        """Source preference for ``op`` in a program lowered for
+        ``platform`` (default: JAX's default backend)."""
+        if self.prefer is not None:
+            return self.prefer
+        by_platform = PLATFORM_PREFER.get(op, {})
+        if by_platform and platform is None:
+            platform = jax.default_backend()
+        return by_platform.get(platform, DEFAULT_PREFER)
+
+    def resolve(self, op: str, *, specialization: str | None = None,
+                platform: str | None = None) -> KernelImpl:
+        prefer = self.order(op, platform)
+        key = (op, specialization, prefer)
         version = self.registry.version
         hit = self._resolve_cache.get(key)
         if hit is not None and hit[0] == version:
             return hit[1]
         impl = self.registry.resolve(
-            op, self.device_kind, self.prefer, specialization=specialization
+            op, self.device_kind, prefer, specialization=specialization
         )
         self._resolve_cache[key] = (version, impl)
         return impl
@@ -107,14 +138,30 @@ def use(
 
 
 def op(name: str, *args: Any, specialization: str | None = None, **kwargs: Any) -> Any:
-    """Dispatch a logical op through the active context (trace-time resolved)."""
+    """Dispatch a logical op through the active context (trace-time resolved).
+
+    An op of :data:`PLATFORM_PREFER` under the default preference is staged
+    for each platform and picked when the program is lowered; the trace
+    records the source of the default backend."""
     ctx = _CTX.get()
     impl = ctx.resolve(name, specialization=specialization)
     if ctx.trace is not None:
         ctx.trace.record(name, impl)
-    if impl.source == "pallas" and ctx.interpret:
-        kwargs = dict(kwargs, interpret=True)
-    return impl.fn(*args, **kwargs)
+
+    def call(impl: KernelImpl) -> Callable[..., Any]:
+        kw = dict(kwargs, interpret=True) if (
+            impl.source == "pallas" and ctx.interpret) else kwargs
+        return lambda *a: impl.fn(*a, **kw)
+
+    if ctx.prefer is None and name in PLATFORM_PREFER:
+        other = ctx.resolve(name, specialization=specialization, platform="")
+        staged = {p: ctx.resolve(name, specialization=specialization, platform=p)
+                  for p in PLATFORM_PREFER[name]}
+        if any(i is not other for i in staged.values()):
+            return jax.lax.platform_dependent(
+                *args, default=call(other),
+                **{p: call(i) for p, i in staged.items()})
+    return call(impl)(*args)
 
 
 def resolve(name: str, *, specialization: str | None = None) -> KernelImpl:

@@ -231,6 +231,8 @@ class OverheadLedger:
     _SYNC_ZERO = {
         "engine_steps": 0.0,        # ServeEngine.step calls
         "host_syncs": 0.0,          # waits for the device (see host_sync)
+        "decode_kv_pages_live": 0.0,    # pages mapped to the slots a decode advances
+        "decode_kv_pages_table": 0.0,   # slots x table width, per decode launch
     }
 
     _PREFIX_ZERO = {
@@ -737,9 +739,17 @@ class OverheadLedger:
         with self._lock:
             self._sync["host_syncs"] += float(n)
 
+    def record_decode_pages(self, *, live: int, table: int) -> None:
+        """One paged decode launch: ``live`` pages mapped to the slots it
+        advances, of ``table`` entries in its block table.  Their ratio is
+        the share of the table that a length-bounded attention reads."""
+        with self._lock:
+            self._sync["decode_kv_pages_live"] += float(live)
+            self._sync["decode_kv_pages_table"] += float(table)
+
     def sync_split(self) -> dict[str, float]:
         """Engine steps, host syncs, and syncs per step (0.0 with no
-        steps)."""
+        steps), and the KV pages of paged decode launches."""
         with self._lock:
             out = dict(self._sync)
         steps = out["engine_steps"]

@@ -230,9 +230,11 @@ def xla_paged_decode_attention(q, k_pages, v_pages, block_table, length, *,
     and the downstream math is *the same function*, the result is
     bitwise-identical to :func:`xla_decode_attention` over an equivalent
     dense cache — the property the paged serving engine's equivalence
-    guarantee rests on.  (The Pallas kernel instead resolves pages on the
-    HBM→VMEM stream and never materializes the dense copy.)  With
-    ``layer`` the pools are stacks [L, P, Hkv, ps, hd], gathered at
+    guarantee rests on, and why the CPU runs this source.  It gathers every
+    table page of every slot whatever the length; a TPU runs the Pallas
+    kernel instead (``core.dispatch.PLATFORM_PREFER``), which copies only
+    each slot's live pages HBM→VMEM and never materializes the dense copy.
+    With ``layer`` the pools are stacks [L, P, Hkv, ps, hd], gathered at
     ``(layer, block_table)`` so the layer's pool is never sliced out."""
     kg = ref.gather_kv_pages(k_pages, block_table, layer)
     vg = ref.gather_kv_pages(v_pages, block_table, layer)

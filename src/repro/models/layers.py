@@ -377,19 +377,26 @@ def attention_decode_paged(
     pos: jax.Array,                    # scalar or [B]: tokens already cached
     cfg: ArchConfig,
     layer: jax.Array,                  # scalar: this layer's index in the stack
+    lengths: jax.Array | None = None,  # [B] rows attended; default pos + 1
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Single-token decode against a paged KV cache.
 
     Identical q/k/v/rope math to :func:`attention_decode`; the only change
     is *where* KV lives: the new token is written into the pool page the
     block table maps its position to, and attention runs via the
-    ``paged_decode_attention`` op (whose XLA source gathers pages back into
-    the dense layout and then executes the same dense decode-attention
-    function — which is what makes paged serving bitwise-identical to
-    dense, not merely allclose).  The pools hold every layer of a segment
-    and are returned whole: the write touches one row per sequence and
-    attention reads layer ``layer``'s pages through the block table, so
-    neither copies the layer's pool.
+    ``paged_decode_attention`` op.  Its XLA source (the one the CPU runs)
+    gathers pages back into the dense layout and then executes the same
+    dense decode-attention function, which is what makes paged serving
+    bitwise-identical to dense, not merely allclose.  A TPU runs the
+    length-bounded Pallas kernel instead, which reads only the pages below
+    each sequence's bound (``core.dispatch.PLATFORM_PREFER``).  The pools
+    hold every layer of a segment and are returned whole: the write touches
+    one row per sequence and attention reads layer ``layer``'s pages
+    through the block table, so neither copies the layer's pool.
+
+    ``lengths`` bounds the rows each sequence attends to (at least 1).  The
+    fused decode passes 1 for slots it does not advance, so they read one
+    page, not their stale extent; their outputs are discarded.
     """
     B = x.shape[0]
     ps = k_pool.shape[3]
@@ -404,7 +411,7 @@ def attention_decode_paged(
     with jax.named_scope("attn.core"):
         out = dispatch.op(
             "paged_decode_attention", q, k_pool, v_pool, block_table,
-            posb + 1, layer=layer,
+            posb + 1 if lengths is None else lengths, layer=layer,
         )
     return _decode_out(p, out), k_pool, v_pool
 

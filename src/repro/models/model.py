@@ -282,13 +282,14 @@ def _window_clip(cfg: ArchConfig, kv: dict) -> dict:
 
 
 def apply_layer_decode(cfg, kind, p, x, cache, pos, moe_info=None,
-                       block_table=None, layer=None):
+                       block_table=None, layer=None, lengths=None):
     """One-token step. Returns (x, new_cache).
 
     With ``block_table`` the k/v leaves are the segment's stacked page
     *pools* ([L, P, Hkv, page_size, hd]) shared by the whole batch, this
     layer's being ``[layer]``, and attention routes through the paged write
-    + block-table kernel.  Only plain position-indexed GQA caches support
+    + block-table kernel, attending to ``lengths`` rows per sequence
+    (default ``pos + 1``).  Only plain position-indexed GQA caches support
     paging; recurrent / latent / windowed layouts raise.
     """
     if block_table is not None and (kind not in ("dense", "moe")
@@ -328,7 +329,8 @@ def apply_layer_decode(cfg, kind, p, x, cache, pos, moe_info=None,
         new_cache.update({"ckv": ckv, "krope": krope})
     elif block_table is not None:
         a, pk, pv = layers.attention_decode_paged(
-            p["attn"], h, cache["k"], cache["v"], block_table, pos, cfg, layer
+            p["attn"], h, cache["k"], cache["v"], block_table, pos, cfg, layer,
+            lengths,
         )
         new_cache.update({"k": pk, "v": pv})
     else:
@@ -634,7 +636,9 @@ class DecoderLM:
         attention layers to the paged KV path: k/v leaves of ``segments``
         are then global page pools, written and read through the table (see
         :mod:`repro.serve.paged`).  The table itself is engine-owned and not
-        part of the returned cache.  The layer scan then carries each
+        part of the returned cache, as is an optional ``cache["lengths"]``
+        ([B] int32, at least 1): the rows each sequence attends to, by
+        default ``pos + 1``.  The layer scan then carries each
         segment's stacked pools instead of scanning them: a token's K/V is
         written into the stack in place, where slicing out each layer's pool
         and restacking it would copy the whole pool every step.
@@ -643,6 +647,7 @@ class DecoderLM:
         h = layers.embed_tokens(params["embed"], tokens)
         pos = cache["pos"]
         block_table = cache.get("block_table")
+        lengths = cache.get("lengths")
         new_segs = []
 
         for seg, seg_params, seg_cache in zip(
@@ -658,7 +663,7 @@ class DecoderLM:
                         x, pools[str(i)] = apply_layer_decode(
                             cfg, kind, unit_params[str(i)], x, pools[str(i)],
                             pos, moe_info=moe_info, block_table=block_table,
-                            layer=layer,
+                            layer=layer, lengths=lengths,
                         )
                         x = shard_act(x.astype(dt0), "batch", None, None)
                     return (x, pools), None

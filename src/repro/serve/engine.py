@@ -2031,6 +2031,10 @@ class ServeEngine:
                     # growth happens on the host *between* launches (a
                     # launch is sized so it never outruns its mapped pages)
                     cache["block_table"] = table
+                    # a slot not advancing attends to one row: a free or
+                    # mid-prefill slot reads the scratch page once, not its
+                    # stale extent (its token is discarded below)
+                    cache["lengths"] = jnp.where(active, pos + 1, 1)
                 logits, new_cache = model.decode_step(
                     params, tok[:, None], cache
                 )
@@ -2333,6 +2337,10 @@ class ServeEngine:
                     for slot in self._active
                 }
             tbl = self._table if self.paged else None
+            if self.paged and self.ledger is not None:
+                self.ledger.record_decode_pages(
+                    live=int(self._mapped[active].sum()),
+                    table=self.slots * self.table_pages)
             if self.paged and self._prefilling:
                 # a mid-prefill slot already has real pages mapped, but it
                 # is not in this launch's active set — its masked dummy

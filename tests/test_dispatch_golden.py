@@ -83,3 +83,46 @@ def test_dispatch_trace_records_impl_source_switch():
 
 def test_op_counts_empty_trace():
     assert dispatch.DispatchTrace().op_counts() == {}
+
+
+@pytest.mark.parametrize("platform,prefer,source", [
+    ("cpu", None, "xla"),
+    ("tpu", None, "pallas"),
+    ("cpu", ("pallas", "xla", "reference"), "pallas"),
+    ("tpu", ("xla", "reference"), "xla"),
+    ("tpu", ("reference",), "reference"),
+])
+def test_paged_attention_source_follows_the_platform(platform, prefer, source):
+    """Under the default preference the paged decode attention takes the
+    Pallas kernel in a program lowered for a TPU and XLA elsewhere; an
+    explicit preference wins on every platform, and no other op moves."""
+    with dispatch.use(prefer=prefer) as ctx:
+        got = ctx.resolve("paged_decode_attention", platform=platform)
+        others = {op: ctx.resolve(op, platform=platform).source
+                  for op in ("matmul", "rmsnorm", "decode_attention",
+                             "flash_attention")}
+    assert got.source == source
+    assert set(others.values()) == {(prefer or ("xla",))[0]}
+
+
+def test_the_default_backend_lowers_the_xla_paged_attention():
+    """On the CPU the default dispatch lowers the XLA gather: no Pallas
+    kernel in the program, and the trace records the XLA source."""
+    import jax
+
+    from repro.kernels import ops
+
+    q = jnp.ones((2, 4, 16), jnp.bfloat16)
+    pages = jnp.ones((5, 2, 8, 16), jnp.bfloat16)
+    table = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    lengths = jnp.asarray([3, 16], jnp.int32)
+    trace = dispatch.DispatchTrace()
+    fn = jax.jit(lambda *a: dispatch.op("paged_decode_attention", *a))
+    with dispatch.use(trace=trace):
+        hlo = fn.lower(q, pages, pages, table, lengths).as_text()
+        got = fn(q, pages, pages, table, lengths)
+    assert jax.default_backend() == "cpu"
+    assert "tpu_custom_call" not in hlo and "pallas" not in hlo.lower()
+    assert [n for _, n in trace.events] == ["paged_decode_attention:xla:generic"]
+    want = ops.xla_paged_decode_attention(q, pages, pages, table, lengths)
+    assert jnp.array_equal(got, want)

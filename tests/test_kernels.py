@@ -311,17 +311,83 @@ def _paged_case(B, hkv, ps, n_pages, pool_pages, D, dtype, seed=0):
     return k_pages, v_pages, table, lengths
 
 
+def _case(hq, hkv, ps, n_pages, lengths="random", block_pages=None,
+          tables="disjoint", layer=None, nan=False, id=None):
+    return pytest.param(hq, hkv, ps, n_pages,
+                        dict(lengths=lengths, block_pages=block_pages,
+                             tables=tables, layer=layer, nan=nan),
+                        id=id or f"{hq}-{hkv}-{ps}-{n_pages}")
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("hq,hkv,ps,n_pages", [(8, 2, 16, 4), (4, 4, 32, 2),
-                                               (8, 1, 8, 8)])
-def test_pallas_paged_decode_attention_sweep(dtype, hq, hkv, ps, n_pages):
-    B, D = 2, 32
+@pytest.mark.parametrize("hq,hkv,ps,n_pages,case", [
+    _case(8, 2, 16, 4), _case(4, 4, 32, 2), _case(8, 1, 8, 8),
+    # blocks of 2 pages (16 rows), so a slot spans several blocks
+    _case(8, 2, 8, 8, "one", 2, id="bound-1"),
+    _case(8, 2, 8, 8, "block-edge", 2, id="block-edge"),
+    _case(8, 2, 8, 8, "block-edge+1", 2, id="block-edge-plus-1"),
+    _case(8, 2, 8, 8, "full", 2, id="full-table"),
+    _case(8, 2, 8, 8, "full", id="full-table-one-block"),
+    _case(4, 1, 8, 8, "random", 3, "scrambled", id="scrambled-shared"),
+    _case(8, 2, 8, 8, "random", 2, layer=2, id="layer-2-of-stack"),
+    _case(8, 2, 8, 8, "random", 2, "scrambled", nan=True,
+          id="nan-past-length"),
+])
+def test_pallas_paged_decode_attention_sweep(dtype, hq, hkv, ps, n_pages, case,
+                                             monkeypatch):
+    """The length-bounded kernel against the gather-then-dense oracle.
+
+    ``nan``: every pool page past each slot's length, and every page the
+    tables do not map, holds NaN, and the oracle reads clean copies.  A NaN
+    that reached the P·V product would survive any masking of the logits,
+    so equality shows those pages are never copied."""
+    from repro.kernels import decode_attention as dec_k
+
+    B, D, L = 3, 32, 3
+    rng = np.random.default_rng(7)
+    if case["block_pages"] is not None:
+        monkeypatch.setattr(dec_k, "MIN_BLOCK_BYTES", 0)
+        monkeypatch.setattr(dec_k, "MIN_BLOCK_ROWS", case["block_pages"] * ps)
     q = _rand((B, hq, D), dtype)
     k_pages, v_pages, table, lengths = _paged_case(
         B, hkv, ps, n_pages, B * n_pages + 3, D, dtype)
-    got = pallas_paged_decode(q, k_pages, v_pages, table, lengths,
-                              interpret=True)
-    want = ref.paged_decode_attention(q, k_pages, v_pages, table, lengths)
+    P = k_pages.shape[0]
+    rows = ps * dec_k.paged_block_pages(ps, hkv, D, jnp.dtype(dtype).itemsize,
+                                        n_pages)
+    lengths = {
+        "random": lengths,
+        "one": jnp.ones(B, jnp.int32),
+        "block-edge": jnp.asarray([rows, 2 * rows, 3 * rows], jnp.int32),
+        "block-edge+1": jnp.asarray([rows + 1, 2 * rows + 1, 1], jnp.int32),
+        "full": jnp.full(B, n_pages * ps, jnp.int32),
+    }[case["lengths"]]
+    if case["tables"] == "scrambled":
+        # any pool page, shared between slots (as prefix sharing maps them)
+        table = jnp.asarray(rng.integers(1, P, (B, n_pages)), jnp.int32)
+        table = table.at[1, :2].set(table[0, :2])
+    clean = k_pages, v_pages
+    if case["nan"]:
+        live = -(-np.asarray(lengths) // ps)
+        tab = np.array(table)
+        keep = np.zeros(P, bool)
+        for b in range(B):
+            keep[tab[b, :live[b]]] = True
+            tab[b, live[b]:] = 0                   # unmapped: the scratch page
+        table = jnp.asarray(tab)
+        poison = jnp.asarray(~keep)[:, None, None, None]
+        k_pages, v_pages = (jnp.where(poison, jnp.nan, x).astype(dtype)
+                            for x in clean)
+    want = ref.paged_decode_attention(q, *clean, table, lengths)
+    if case["layer"] is not None:
+        stack = lambda x: jnp.stack(                # noqa: E731
+            [jnp.roll(x, i + 1, axis=0) for i in range(L)]).at[case["layer"]].set(x)
+        k_pages, v_pages = stack(k_pages), stack(v_pages)
+        got = pallas_paged_decode(q, k_pages, v_pages, table, lengths,
+                                  layer=jnp.int32(case["layer"]), interpret=True)
+    else:
+        got = pallas_paged_decode(q, k_pages, v_pages, table, lengths,
+                                  interpret=True)
+    assert (rows < n_pages * ps) == (case["block_pages"] is not None)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
     )

@@ -97,6 +97,21 @@ def test_paged_decode_attention_compiles(one_chip, page_size):
     assert _has_kernel(_compile(paged_decode_attention, *args))
 
 
+def test_paged_decode_attention_compiles_at_granite_widths(one_chip):
+    """8 KV heads in groups of 4 (Granite-3-8B): the kernel's blocks follow
+    the page's shape, with no path of their own."""
+    hq, hkv, page_size = 32, 8, 16
+    n_pages = B * (T // page_size) + 1
+    args = (
+        _spec((B, hq, HD), BF16, one_chip),
+        _spec((n_pages, hkv, page_size, HD), BF16, one_chip),
+        _spec((n_pages, hkv, page_size, HD), BF16, one_chip),
+        _spec((B, T // page_size), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip),
+    )
+    assert _has_kernel(_compile(paged_decode_attention, *args))
+
+
 def test_decode_attention_compiles(one_chip):
     args = (
         _spec((B, HQ, HD), BF16, one_chip),
@@ -188,39 +203,78 @@ def _pool_copies(hlo: str, layer_shape: tuple[int, ...]) -> list[str]:
     return out
 
 
-@pytest.mark.parametrize("prefer", ["xla", "pallas"])
-def test_yi6b_fused_paged_decode_updates_pool_in_place(one_chip, prefer):
+@pytest.fixture(scope="module")
+def fused_k4(one_chip):
     """The engine's fused K=4 paged decode at Yi-6B widths (2 layers, 12
-    slots, ``max_len`` 4096, page 16): the pool is donated and aliased to
-    the output, and no copy, dynamic-slice or dynamic-update-slice holds a
-    pool leaf or one layer of one."""
+    slots, ``max_len`` 4096, page 16), compiled for the described chip under
+    a dispatch preference (``"default"``: the context's own), once each:
+    ``(compiled, pool leaves, dispatch trace)``."""
     cfg = dataclasses.replace(YI, num_layers=2)
     model = build_model(cfg)
     shard = lambda s: _spec(s.shape, s.dtype, one_chip)   # noqa: E731
     params = jax.tree.map(shard, abstract_params(model.param_specs()))
     slots, page_size = 12, 16
-    eng = ServeEngine(model, params, batch_slots=slots, max_len=T,
-                      paged=True, page_size=page_size, decode_fusion=4)
-    segments = jax.tree.map(
-        lambda s: _spec((s.shape[0], eng.pool_pages, *s.shape[2:]), s.dtype,
-                        one_chip),
-        model.cache_specs(1, page_size)["segments"],
-    )
-    per_slot = lambda dtype: _spec((slots,), dtype, one_chip)   # noqa: E731
-    fn = eng._fused_decode_fn(4)
-    with dispatch.use(prefer=dispatch.policy_from_flag(prefer)):
-        compiled = fn.lower(
-            params, segments,
-            _spec((slots, T // page_size), jnp.int32, one_chip),
-            per_slot(jnp.int32), per_slot(jnp.int32),
-            _spec((slots, 2), jnp.uint32, one_chip),
-            per_slot(jnp.int32), per_slot(jnp.bool_), per_slot(jnp.int32),
-        ).compile()
-    assert fn.__name__ == "decode_fused_k4_paged"
-    leaves = jax.tree.leaves(segments)
+    done = {}
+
+    def compile_for(prefer):
+        if prefer in done:
+            return done[prefer]
+        eng = ServeEngine(model, params, batch_slots=slots, max_len=T,
+                          paged=True, page_size=page_size, decode_fusion=4)
+        segments = jax.tree.map(
+            lambda s: _spec((s.shape[0], eng.pool_pages, *s.shape[2:]),
+                            s.dtype, one_chip),
+            model.cache_specs(1, page_size)["segments"],
+        )
+        per_slot = lambda dtype: _spec((slots,), dtype, one_chip)   # noqa: E731
+        fn = eng._fused_decode_fn(4)
+        assert fn.__name__ == "decode_fused_k4_paged"
+        trace = dispatch.DispatchTrace()
+        order = None if prefer == "default" else dispatch.policy_from_flag(prefer)
+        with dispatch.use(prefer=order, trace=trace):
+            compiled = fn.lower(
+                params, segments,
+                _spec((slots, T // page_size), jnp.int32, one_chip),
+                per_slot(jnp.int32), per_slot(jnp.int32),
+                _spec((slots, 2), jnp.uint32, one_chip),
+                per_slot(jnp.int32), per_slot(jnp.bool_), per_slot(jnp.int32),
+            ).compile()
+        done[prefer] = compiled, jax.tree.leaves(segments), trace
+        return done[prefer]
+
+    return compile_for
+
+
+@pytest.mark.parametrize("prefer", ["xla", "pallas", "default"])
+def test_yi6b_fused_paged_decode_updates_pool_in_place(fused_k4, prefer):
+    """The engine's fused K=4 paged decode at Yi-6B widths (2 layers, 12
+    slots, ``max_len`` 4096, page 16): the pool is donated and aliased to
+    the output, and no copy, dynamic-slice or dynamic-update-slice holds a
+    pool leaf or one layer of one."""
+    compiled, leaves, _ = fused_k4(prefer)
     pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
     assert _pool_copies(compiled.as_text(), leaves[0].shape[1:]) == []
+
+
+def test_yi6b_fused_paged_decode_runs_the_kernel_by_default(fused_k4):
+    """Under the default dispatch a program for the TPU runs the Pallas
+    paged attention inside ``attn.core``, where the benchmark's scope
+    reader finds it by the compiled HLO's ``op_name``; every other op
+    resolves as under the explicit XLA preference."""
+    from bench import devtrace
+
+    compiled, _, trace = fused_k4("default")
+    text = compiled.as_text()
+    kernels = re.findall(r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                         text)
+    names = devtrace.hlo_op_names(text)
+    assert kernels and all("/attn.core/" in names[k] for k in kernels), kernels
+    assert " conditional(" not in text
+    _, _, xla_trace = fused_k4("xla")
+    assert trace.events == xla_trace.events
+    assert {n for op, n in trace.events if op == "paged_decode_attention"} == {
+        "paged_decode_attention:xla:generic"}          # the CPU host's record
 
 
 # Mosaic refuses these two kernels; they are off the serving path.  The PR

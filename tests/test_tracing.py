@@ -150,6 +150,23 @@ def test_a_decode_step_syncs_five_times(engine_model):
     assert led.sync_split()["host_syncs"] - before == 5
 
 
+def test_a_decode_launch_counts_live_and_table_pages(engine_model):
+    """Each paged decode launch adds the pages mapped to the slots it
+    advances, and slots x table width, to ``sync_split()``."""
+    eng, led = _engine(*engine_model)
+    eng.submit(PREFIX, max_new_tokens=12)
+    while not eng._active:
+        eng.step()
+    (slot,) = eng._active
+    before = led.sync_split()
+    eng.step()
+    after = led.sync_split()
+    assert eng.table_pages == 8 and eng.slots == 2
+    assert after["decode_kv_pages_table"] - before["decode_kv_pages_table"] == 16
+    assert (after["decode_kv_pages_live"] - before["decode_kv_pages_live"]
+            == eng._mapped[slot] == 3)   # 16 prompt rows + this launch's 2
+
+
 def test_the_fused_decode_names_its_parts(engine_model):
     """The scopes are HLO metadata: the compiled fused decode carries them
     in its ``op_name``s."""
